@@ -22,6 +22,8 @@ import json
 import time
 import traceback
 
+from repro.launch.compile_cache import enable_compile_cache
+
 
 def _bench(name, fn, results):
     t0 = time.time()
@@ -256,17 +258,15 @@ def _manifest() -> dict:
            "git_sha": _rec.git_sha(),
            "platform": platform.platform(),
            "python": sys.version.split()[0]}
-    try:
-        import jax
+    import jax
 
-        man["jax_version"] = jax.__version__
-        man["backend"] = jax.default_backend()
-    except Exception:  # pragma: no cover
-        pass
+    man["jax_version"] = jax.__version__
+    man["backend"] = jax.default_backend()
     return man
 
 
-def main(argv=None) -> None:
+def main(argv=None) -> int:
+    """Runs the selected benches; returns 1 if any of them raised."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--json", default="BENCH_local.json", metavar="OUT",
                     help="JSON artifact path (default BENCH_local.json; "
@@ -274,6 +274,7 @@ def main(argv=None) -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated substrings: run matching benches only")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     wanted = [w for w in (args.only or "").split(",") if w]
     results = {}
     print("name,us_per_call,derived")
@@ -286,7 +287,12 @@ def main(argv=None) -> None:
             json.dump({"manifest": _manifest(), "results": results},
                       f, indent=2, sort_keys=True)
         print(f"# json -> {args.json}")
+    failed = [n for n, r in results.items() if r["status"] != "ok"]
+    if failed:
+        print(f"# failed: {','.join(failed)}")
+        return 1
+    return 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

@@ -134,7 +134,8 @@ class CastCodec(Codec):
 
 class IntQuantCodec(Codec):
     """Symmetric absmax quantization over flat tiles of ``tile`` elements,
-    one fp32 scale each; int4 packs value pairs into int8 words. The flat
+    one fp32 scale each; int4 packs each tile's two halves into int8 words
+    (``kernels.quantize.pack_int4``, the kernels' wire format). The flat
     layout makes the codec shape-agnostic (conv maps, sequences, params);
     the (N, T, D) kernel entry points in ``kernels.ops`` are the layout-
     specialized fast path for the server's aggregation inner loop."""
@@ -159,7 +160,7 @@ class IntQuantCodec(Codec):
         return flat.reshape(-1, self.tile), numel
 
     def encode(self, x, seed=0):
-        from repro.kernels.quantize import hash_uniform
+        from repro.kernels.quantize import hash_uniform, pack_int4
 
         tiles, numel = self._flatten(x)
         absmax = jnp.max(jnp.abs(tiles), axis=1, keepdims=True)
@@ -174,17 +175,16 @@ class IntQuantCodec(Codec):
         q = jnp.clip(jnp.floor(tiles / scale + u),
                      -self.qmax, self.qmax).astype(jnp.int32)
         if self.bits == 4:
-            pairs = q.reshape(q.shape[0], self.tile // 2, 2)
-            q = ((pairs[..., 1] & 15) << 4) | (pairs[..., 0] & 15)
+            q = pack_int4(q)
         return Payload(q.astype(jnp.int8), scale[:, 0], shape=x.shape,
                        dtype=x.dtype, codec=self.name)
 
     def decode(self, p):
-        from repro.kernels.quantize import _unpack_int4
-
         q = p.data.astype(jnp.int32)
         if self.bits == 4:
-            q = _unpack_int4(q)
+            from repro.kernels.quantize import unpack_int4
+
+            q = unpack_int4(q)
         x = q.astype(jnp.float32) * p.scale[:, None]
         numel = int(math.prod(p.shape))
         return x.reshape(-1)[:numel].reshape(p.shape).astype(p.dtype)

@@ -196,6 +196,19 @@ class ClientBank:
             return self.nbytes // n_client_shards(self._mesh)
         return self._gathered_bytes + self._staged_bytes
 
+    def bytes_by_device(self) -> Dict[str, int]:
+        """Bank bytes each device actually holds, read from the arrays'
+        shards — where ``device_bytes`` assumes an even split, this shows
+        whether the ``sharded`` rows really spread. Empty for ``host``."""
+        out: Dict[str, int] = {}
+        if self.backend == "host":
+            return out
+        for leaf in jax.tree.leaves(self._tree):
+            for shard in leaf.addressable_shards:
+                key = str(shard.device)
+                out[key] = out.get(key, 0) + shard.data.nbytes
+        return out
+
     def _note_device_bytes(self) -> None:
         self._peak_device_bytes = max(self._peak_device_bytes,
                                       self.device_bytes)
@@ -208,6 +221,7 @@ class ClientBank:
             "device_bytes": self.device_bytes,
             "device_bytes_peak": max(self._peak_device_bytes,
                                      self.device_bytes),
+            "bytes_by_device": self.bytes_by_device(),
             "prefetch_hits": self._hits,
             "prefetch_misses": self._misses,
             "gather_wait_s": self._gather_wait_s,

@@ -467,6 +467,13 @@ class ServeEngine:
                 break
         return self.completions
 
+    def lower_step(self):
+        """The jitted decode step, lowered at this engine's shapes (slots,
+        page pools, weights): what one served token compiles to."""
+        return self._step_fn.lower(
+            self.params, self.caches, jnp.asarray(self._cur_tok),
+            jnp.asarray(self._live), self._key, jnp.uint32(0))
+
     # -- summary -----------------------------------------------------------
 
     def summary(self) -> Dict[str, float]:

@@ -14,15 +14,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across JAX versions
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from repro.kernels.platform import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -88,7 +87,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                                              "block_k", "interpret"))
 def flash_attention_bhsd(q, k, v, causal: bool = True, window=None,
                          block_q: int = 128, block_k: int = 128,
-                         interpret: bool = True):
+                         interpret: Optional[bool] = None):
     """q: (B, Hq, S, D); k, v: (B, Hkv, T, D). GQA when Hq > Hkv."""
     B, Hq, S, D = q.shape
     _, Hkv, T, _ = k.shape
@@ -121,8 +120,8 @@ def flash_attention_bhsd(q, k, v, causal: bool = True, window=None,
             pltpu.VMEM((block_q, 1), jnp.float32),  # running denom
             pltpu.VMEM((block_q, D), jnp.float32),  # output accumulator
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)

@@ -1,7 +1,8 @@
 """jit'd public wrappers around the Pallas kernels.
 
-``backend`` selection: "pallas" runs the kernel (interpret=True on CPU —
-the TPU target executes the same kernel compiled); "jnp" runs the oracle.
+``backend`` selection: "pallas" runs the kernel (compiled on a TPU,
+interpreted on other backends — ``kernels.platform``); "jnp" runs the
+oracle.
 Model code calls these, so swapping a kernel in/out is a config flag.
 """
 from __future__ import annotations
@@ -18,8 +19,6 @@ from repro.kernels.grad_agg import grad_agg_reduce
 from repro.kernels.paged_attention import paged_attention_decode
 from repro.kernels.quantize import dequant_agg_reduce, quantize_pack
 from repro.kernels.ssd_scan import ssd_intra_chunk
-
-_ON_TPU = any(d.platform == "tpu" for d in jax.devices())
 
 
 def flash_attention(q, k, v, causal: bool = True, window: Optional[int] = None,
@@ -40,12 +39,10 @@ def flash_attention(q, k, v, causal: bool = True, window: Optional[int] = None,
             kt = jnp.pad(kt, ((0, 0), (0, 0), (0, 0), (0, pad)))
             vt = jnp.pad(vt, ((0, 0), (0, 0), (0, 0), (0, pad)))
             out = flash_attention_bhsd(qt, kt, vt, causal, window,
-                                       block_q, block_k,
-                                       interpret=not _ON_TPU)[..., :D]
+                                       block_q, block_k)[..., :D]
         else:
             out = flash_attention_bhsd(qt, kt, vt, causal, window,
-                                       block_q, block_k,
-                                       interpret=not _ON_TPU)
+                                       block_q, block_k)
     return jnp.swapaxes(out, 1, 2)
 
 
@@ -81,7 +78,7 @@ def paged_attention(q, pages_k, pages_v, page_table, lengths,
                 f"paged_attention pallas backend needs head_dim in "
                 f"(64, 128), got {D}; use backend='jnp'")
         out = paged_attention_decode(qg, pages_k, pages_v, page_table,
-                                     lengths, interpret=not _ON_TPU)
+                                     lengths)
     return out[:, :, :G].reshape(slots, Hq, D)
 
 
@@ -110,8 +107,7 @@ def ssd(x, dt, A, B, C, chunk: int, initial_state=None, backend: str = "pallas")
     B_c = Bf.reshape(b, c, chunk, h, n).transpose(0, 3, 1, 2, 4)
     C_c = Cf.reshape(b, c, chunk, h, n).transpose(0, 3, 1, 2, 4)
 
-    y_diag, states = ssd_intra_chunk(xdt_c, dA_c, B_c, C_c,
-                                     interpret=not _ON_TPU)
+    y_diag, states = ssd_intra_chunk(xdt_c, dA_c, B_c, C_c)
 
     # inter-chunk recurrence (jnp; c is small)
     A_cs = jnp.cumsum(dA_c, axis=-1)  # (b,h,c,Q)
@@ -146,7 +142,7 @@ def grad_agg(g, rho, backend: str = "pallas"):
     if backend == "jnp":
         out = ref.grad_agg_ref(g, rho)
     else:
-        out = grad_agg_reduce(g, rho, interpret=not _ON_TPU)
+        out = grad_agg_reduce(g, rho)
     if len(shape) == 4:
         out = out.reshape(shape[1], shape[2], shape[3])
     return out
@@ -165,8 +161,7 @@ def quantize(g, seed=0, bits: int = 8, backend: str = "pallas",
         g = g.reshape(shape[0], shape[1] * shape[2], shape[3])
     if backend == "jnp":
         return ref.quantize_ref(g, seed, bits, block_t, block_d, stochastic)
-    return quantize_pack(g, seed, bits, block_t, block_d, stochastic,
-                         interpret=not _ON_TPU)
+    return quantize_pack(g, seed, bits, block_t, block_d, stochastic)
 
 
 def dequant_agg(q, scales, rho, bits: int = 8, backend: str = "pallas",
@@ -175,5 +170,4 @@ def dequant_agg(q, scales, rho, bits: int = 8, backend: str = "pallas",
     endpoint of the compressed gradient-aggregation path. Returns (T, D)."""
     if backend == "jnp":
         return ref.dequant_agg_ref(q, scales, rho, bits, block_t, block_d)
-    return dequant_agg_reduce(q, scales, rho, bits, block_t, block_d,
-                              interpret=not _ON_TPU)
+    return dequant_agg_reduce(q, scales, rho, bits, block_t, block_d)

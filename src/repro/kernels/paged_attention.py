@@ -26,15 +26,14 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across JAX versions
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from repro.kernels.platform import resolve_interpret
 
 NEG_INF = -1e30
 
@@ -83,7 +82,7 @@ def _paged_kernel(pt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_attention_decode(q, pages_k, pages_v, page_table, lengths,
-                           interpret: bool = True):
+                           interpret: Optional[bool] = None):
     """q: (slots, Hkv, G, D); pools: (Hkv, P, page, D); table: (slots,
     max_pages) int32; lengths: (slots,) int32 INCLUDING the just-written
     query token. Returns (slots, Hkv, G, D)."""
@@ -116,8 +115,8 @@ def paged_attention_decode(q, pages_k, pages_v, page_table, lengths,
         kernel,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid_spec=grid_spec,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(page_table, lengths, q, pages_k, pages_v)

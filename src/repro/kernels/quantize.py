@@ -26,17 +26,14 @@ rides along fully inside VMEM, matching ``grad_agg.py``.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-_ON_TPU = any(d.platform == "tpu" for d in jax.devices())
-
-# renamed TPUCompilerParams -> CompilerParams across JAX versions
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from repro.kernels.platform import resolve_interpret
 
 # xxhash/murmur-style odd multipliers (uint32 arithmetic wraps mod 2^32)
 _K_N = 0x9E3779B1
@@ -59,7 +56,8 @@ def hash_uniform(n, t, d, seed):
     h = h ^ (h >> 13)
     h = h * u32(_M2)
     h = h ^ (h >> 16)
-    return (h >> 8).astype(jnp.float32) * (1.0 / (1 << 24))
+    # the top 24 bits fit int32 exactly; Mosaic has no uint32 -> f32 cast
+    return (h >> 8).astype(jnp.int32).astype(jnp.float32) * (1.0 / (1 << 24))
 
 
 def qmax_for(bits: int) -> int:
@@ -70,7 +68,9 @@ def qmax_for(bits: int) -> int:
 def _quantize_kernel(g_ref, seed_ref, q_ref, s_ref, *, qmax, pack,
                      stochastic, block_t, block_d):
     g = g_ref[...].astype(jnp.float32)  # (N, bt, bd)
-    absmax = jnp.max(jnp.abs(g), axis=(1, 2), keepdims=True)  # (N, 1, 1)
+    # one axis at a time: the TPU compiler aborts on a two-axis reduction
+    absmax = jnp.max(jnp.max(jnp.abs(g), axis=2, keepdims=True), axis=1,
+                     keepdims=True)  # (N, 1, 1)
     # multiply by the 1/qmax constant rather than divide: XLA strength-
     # reduces constant divides to an approximate reciprocal, which would
     # break bit-equality between the jitted kernel and the eager oracle
@@ -86,18 +86,20 @@ def _quantize_kernel(g_ref, seed_ref, q_ref, s_ref, *, qmax, pack,
         u = 0.5  # floor(x/s + 0.5) == round-to-nearest
     q = jnp.clip(jnp.floor(g / scale + u), -qmax, qmax).astype(jnp.int32)
     if pack:
-        N, bt, bd = g.shape
-        pairs = q.reshape(N, bt, bd // 2, 2)
-        q = ((pairs[..., 1] & 15) << 4) | (pairs[..., 0] & 15)
+        q = pack_int4(q)
     q_ref[...] = q.astype(jnp.int8)
-    s_ref[...] = scale.astype(jnp.float32)
+    # one SMEM scalar per (client, tile), in the flat (N, T/bt, D/bd) order
+    i, j = pl.program_id(0), pl.program_id(1)
+    nt, nd = pl.num_programs(0), pl.num_programs(1)
+    for c in range(g.shape[0]):
+        s_ref[(c * nt + i) * nd + j] = scale[c, 0, 0]
 
 
 @functools.partial(jax.jit, static_argnames=(
     "bits", "block_t", "block_d", "stochastic", "interpret"))
 def quantize_pack(g, seed=0, bits: int = 8, block_t: int = 256,
                   block_d: int = 256, stochastic: bool = True,
-                  interpret: bool = not _ON_TPU):
+                  interpret: Optional[bool] = None):
     """g: (N, T, D) per-client smashed data/grads. Returns
     (q: (N, T, D·bits/8) int8, scales: (N, T/bt, D/bd) f32)."""
     N, T, D = g.shape
@@ -111,51 +113,69 @@ def quantize_pack(g, seed=0, bits: int = 8, block_t: int = 256,
     kernel = functools.partial(
         _quantize_kernel, qmax=qmax_for(bits), pack=pack,
         stochastic=stochastic, block_t=block_t, block_d=block_d)
-    return pl.pallas_call(
+    nt, nd = T // block_t, D // block_d
+    q, scales = pl.pallas_call(
         kernel,
         out_shape=(
             jax.ShapeDtypeStruct((N, T, D // 2 if pack else D), jnp.int8),
-            jax.ShapeDtypeStruct((N, T // block_t, D // block_d), jnp.float32),
+            jax.ShapeDtypeStruct((N * nt * nd,), jnp.float32),
         ),
-        grid=(T // block_t, D // block_d),
+        grid=(nt, nd),
         in_specs=[
             pl.BlockSpec((N, block_t, block_d), lambda i, j: (0, i, j)),
             pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=(
             pl.BlockSpec((N, block_t, bdq), lambda i, j: (0, i, j)),
-            pl.BlockSpec((N, 1, 1), lambda i, j: (0, i, j)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+        # sequential: every grid step writes its scalars into the one
+        # SMEM scale array, which a core split would not merge
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(g, seed_arr)
+    return q, scales.reshape(N, nt, nd)
 
 
-def _unpack_int4(q):
-    """(…, D/2) packed int8 -> (…, D) int32 in [-8, 7]."""
-    lo = q & 15
-    hi = (q >> 4) & 15
+def pack_int4(q):
+    """(…, bd) int32 in [-8, 7] -> (…, bd/2) int32 words of one tile row:
+    element j in the low nibble, element j + bd/2 in the high one. Two
+    halves rather than adjacent pairs, because the TPU compiler lowers a
+    lane slice but not the (bd/2, 2) reshape that pairing needs."""
+    h = q.shape[-1] // 2
+    return ((q[..., h:] & 15) << 4) | (q[..., :h] & 15)
+
+
+def unpack_int4(w):
+    """Inverse of :func:`pack_int4`: (…, bd/2) words -> (…, bd) int32."""
+    lo = w & 15
+    hi = (w >> 4) & 15
     lo = jnp.where(lo >= 8, lo - 16, lo)
     hi = jnp.where(hi >= 8, hi - 16, hi)
-    return jnp.stack([lo, hi], axis=-1).reshape(q.shape[:-1] + (-1,))
+    return jnp.concatenate([lo, hi], axis=-1)
 
 
 def _dequant_agg_kernel(q_ref, s_ref, rho_ref, o_ref, *, pack):
-    q = q_ref[...].astype(jnp.int32)  # (N, bt, bdq)
-    if pack:
-        q = _unpack_int4(q)
-    scale = s_ref[...].astype(jnp.float32)  # (N, 1, 1)
-    rho = rho_ref[...].astype(jnp.float32)  # (N, 1)
-    g = q.astype(jnp.float32) * scale
-    o_ref[...] = jnp.einsum("ntd,nz->td", g, rho).astype(o_ref.dtype)
+    # client by client: ρ_n is an SMEM scalar, and Mosaic has no matmul
+    # that contracts the untiled client axis
+    acc = None
+    for n in range(q_ref.shape[0]):
+        q = q_ref[n].astype(jnp.int32)  # (bt, bdq)
+        if pack:
+            q = unpack_int4(q)
+        scale = s_ref[(n * pl.num_programs(0) + pl.program_id(0))
+                      * pl.num_programs(1) + pl.program_id(1)]
+        g = (q.astype(jnp.float32) * scale) * rho_ref[n]
+        acc = g if acc is None else acc + g
+    o_ref[...] = acc.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
     "bits", "block_t", "block_d", "interpret"))
 def dequant_agg_reduce(q, scales, rho, bits: int = 8, block_t: int = 256,
-                       block_d: int = 256, interpret: bool = not _ON_TPU):
+                       block_d: int = 256, interpret: Optional[bool] = None):
     """Fused decode + eq. 5: Σ_n ρ[n]·scale[n,tile]·q[n]. q: (N, T, Dq)
     int8 payloads from ``quantize_pack``; scales: (N, T/bt, D/bd);
     rho: (N,). The (block_t, block_d) tiling must match the encoder's —
@@ -168,19 +188,18 @@ def dequant_agg_reduce(q, scales, rho, bits: int = 8, block_t: int = 256,
     assert scales.shape == (N, T // block_t, D // block_d), (
         scales.shape, (N, T // block_t, D // block_d))
     bdq = block_d // 2 if pack else block_d
-    rho2 = rho.reshape(N, 1).astype(jnp.float32)
     return pl.pallas_call(
         functools.partial(_dequant_agg_kernel, pack=pack),
         out_shape=jax.ShapeDtypeStruct((T, D), jnp.float32),
         grid=(T // block_t, D // block_d),
         in_specs=[
             pl.BlockSpec((N, block_t, bdq), lambda i, j: (0, i, j)),
-            pl.BlockSpec((N, 1, 1), lambda i, j: (0, i, j)),
-            pl.BlockSpec((N, 1), lambda i, j: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((block_t, block_d), lambda i, j: (i, j)),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
         ),
-        interpret=interpret,
-    )(q, scales, rho2)
+        interpret=resolve_interpret(interpret),
+    )(q, scales.astype(jnp.float32).reshape(-1), rho.astype(jnp.float32))

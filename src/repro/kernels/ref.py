@@ -151,20 +151,30 @@ def quantize_ref(g, seed=0, bits: int = 8, block_t: int = 256,
     q = jnp.clip(jnp.floor(g.astype(jnp.float32) / s_full + u),
                  -qmax, qmax).astype(jnp.int32)
     if bits == 4:
-        pairs = q.reshape(N, T, D // 2, 2)
-        q = ((pairs[..., 1] & 15) << 4) | (pairs[..., 0] & 15)
+        # each tile row's first half in the low nibbles, second in the high
+        h = block_d // 2
+        q = q.reshape(N, T, D // block_d, block_d)
+        q = ((q[..., h:] & 15) << 4) | (q[..., :h] & 15)
+        q = q.reshape(N, T, D // 2)
     return q.astype(jnp.int8), scales
+
+
+def unpack_int4_ref(q, block_d: int = 256):
+    """(N, T, D/2) int4 payload words -> (N, T, D) int32 codes: each tile
+    row's low nibbles are its first half, the high nibbles its second."""
+    N, T, Dq = q.shape
+    bd = min(block_d, 2 * Dq)
+    w = q.astype(jnp.int32).reshape(N, T, 2 * Dq // bd, bd // 2)
+    # sign-extend each nibble by shifting it to the top of the int32
+    qi = jnp.concatenate([(w << 28) >> 28, (w << 24) >> 28], axis=-1)
+    return qi.reshape(N, T, 2 * Dq)
 
 
 def dequant_agg_ref(q, scales, rho, bits: int = 8, block_t: int = 256,
                     block_d: int = 256):
     """Oracle for kernels.quantize.dequant_agg_reduce: unpack, rescale and
     ρ-reduce N payloads. Returns (T, D) f32."""
-    from repro.kernels.quantize import _unpack_int4
-
-    qi = q.astype(jnp.int32)
-    if bits == 4:
-        qi = _unpack_int4(qi)
+    qi = unpack_int4_ref(q, block_d) if bits == 4 else q.astype(jnp.int32)
     N, T, D = qi.shape
     block_t = min(block_t, T)
     block_d = min(block_d, D)

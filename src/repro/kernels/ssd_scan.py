@@ -11,34 +11,35 @@ the off-diagonal contribution stay in jnp — see repro.kernels.ops.ssd.
 
 TPU adaptation: the CUDA version's warp-level scan becomes a chunk x chunk
 lower-triangular matmul feeding the MXU; chunk length (128) and head_dim
-(64) are lane-aligned; the decay matrix is built from a cumulative sum
-along the chunk axis in VREGs.
+(64) are lane-aligned; the decay matrix is built in VMEM from the chunk's
+cumulative log-decay, which XLA computes before the kernel.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across JAX versions
-_CompilerParams = getattr(pltpu, "CompilerParams",
-                          getattr(pltpu, "TPUCompilerParams", None))
+from repro.kernels.platform import resolve_interpret
 
 
-def _ssd_chunk_kernel(xdt_ref, dA_ref, b_ref, c_ref, y_ref, state_ref):
-    # blocks: xdt (1,1,Q,P), dA (1,1,1,Q), b/c (1,1,Q,N)
+def _ssd_chunk_kernel(xdt_ref, csr_ref, csc_ref, b_ref, c_ref, y_ref,
+                      state_ref):
+    # blocks: xdt (1,1,1,Q,P), cumulative log-decay as a row (1,1,1,1,Q)
+    # and as a column (1,1,1,Q,1), b/c (1,1,1,Q,N)
     xdt = xdt_ref[0, 0, 0].astype(jnp.float32)  # (Q, P)
-    dA = dA_ref[0, 0, 0].astype(jnp.float32)  # (Q,)
+    cs_row = csr_ref[0, 0, 0]  # (1, Q)
+    cs_col = csc_ref[0, 0, 0]  # (Q, 1)
     B = b_ref[0, 0, 0].astype(jnp.float32)  # (Q, N)
     C = c_ref[0, 0, 0].astype(jnp.float32)  # (Q, N)
     Q = xdt.shape[0]
 
-    cs = jnp.cumsum(dA)  # (Q,)
     # segsum: seg[i, j] = cs[i] - cs[j]; valid lower triangle (j <= i)
-    seg = cs[:, None] - cs[None, :]
+    seg = cs_col - cs_row
     ii = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 0)
     jj = jax.lax.broadcasted_iota(jnp.int32, (Q, Q), 1)
     L = jnp.where(jj <= ii, jnp.exp(seg), 0.0)  # (Q, Q)
@@ -51,20 +52,23 @@ def _ssd_chunk_kernel(xdt_ref, dA_ref, b_ref, c_ref, y_ref, state_ref):
     y_ref[0, 0, 0] = y.astype(y_ref.dtype)
 
     # chunk-final state: state[p, n] = Σ_q exp(cs[-1]-cs[q]) B[q,n] xdt[q,p]
-    decay = jnp.exp(cs[-1] - cs)  # (Q,)
-    bw = B * decay[:, None]  # (Q, N)
+    decay = jnp.exp(cs_row[:, Q - 1:] - cs_col)  # (Q, 1)
+    bw = B * decay  # (Q, N)
     state = jax.lax.dot_general(xdt, bw, (((0,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # (P, N)
     state_ref[0, 0, 0] = state
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_intra_chunk(xdt, dA, B, C, interpret: bool = True):
+def ssd_intra_chunk(xdt, dA, B, C, interpret: Optional[bool] = None):
     """xdt: (b, h, c, Q, P) x·dt; dA: (b, h, c, Q) log-decay;
     B, C: (b, h, c, Q, N) head-expanded. Returns (y_diag, chunk_states)."""
     b, h, c, Q, P = xdt.shape
     N = B.shape[-1]
     grid = (b, h, c)
+    # the chunk's cumulative log-decay, in both layouts the kernel needs:
+    # Mosaic lowers neither a cumsum nor a (1, Q) -> (Q, 1) transpose
+    cs = jnp.cumsum(dA.astype(jnp.float32), axis=-1)
     y, states = pl.pallas_call(
         _ssd_chunk_kernel,
         out_shape=(
@@ -74,7 +78,8 @@ def ssd_intra_chunk(xdt, dA, B, C, interpret: bool = True):
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, 1, Q, P), lambda i, j, k: (i, j, k, 0, 0)),
-            pl.BlockSpec((1, 1, 1, Q), lambda i, j, k: (i, j, k, 0)),
+            pl.BlockSpec((1, 1, 1, 1, Q), lambda i, j, k: (i, j, k, 0, 0)),
+            pl.BlockSpec((1, 1, 1, Q, 1), lambda i, j, k: (i, j, k, 0, 0)),
             pl.BlockSpec((1, 1, 1, Q, N), lambda i, j, k: (i, j, k, 0, 0)),
             pl.BlockSpec((1, 1, 1, Q, N), lambda i, j, k: (i, j, k, 0, 0)),
         ],
@@ -82,9 +87,9 @@ def ssd_intra_chunk(xdt, dA, B, C, interpret: bool = True):
             pl.BlockSpec((1, 1, 1, Q, P), lambda i, j, k: (i, j, k, 0, 0)),
             pl.BlockSpec((1, 1, 1, P, N), lambda i, j, k: (i, j, k, 0, 0)),
         ),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel"),
         ),
-        interpret=interpret,
-    )(xdt, dA, B, C)
+        interpret=resolve_interpret(interpret),
+    )(xdt, cs[..., None, :], cs[..., None], B, C)
     return y, states

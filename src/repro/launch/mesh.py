@@ -14,15 +14,24 @@ from typing import Tuple
 import jax
 
 
+def _mesh(shape, axes):
+    """``jax.make_mesh`` with every axis ``Auto``. The default axis type
+    is ``Explicit``, under which indexing a bank leaf sharded over
+    "data" with a cohort index array raises instead of letting XLA
+    insert the cross-shard gather."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(shape))
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh():
     """1-device mesh for CPU smoke runs (same axis names)."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _mesh((1, 1), ("data", "model"))
 
 
 def make_bank_mesh():
@@ -31,7 +40,7 @@ def make_bank_mesh():
     blocks with no tensor-parallel dim to feed "model". Standard axis
     names, so ``client_axes``/``bank_sharding`` work on this mesh and on
     the production meshes alike."""
-    return jax.make_mesh((len(jax.devices()), 1), ("data", "model"))
+    return _mesh((len(jax.devices()), 1), ("data", "model"))
 
 
 def client_axes(mesh) -> Tuple[str, ...]:

@@ -24,9 +24,13 @@ import argparse
 import numpy as np
 
 from repro import obs
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main(argv=None):
+    """Serves the queued requests; returns the drained
+    :class:`~repro.core.serve_engine.ServeEngine` (its ``completions`` in
+    finishing order)."""
     p = argparse.ArgumentParser()
     p.add_argument("--arch", required=True)
     p.add_argument("--preset", default="smoke", choices=["smoke", "full"])
@@ -46,6 +50,9 @@ def main(argv=None):
                    help="0 = greedy (fused argmax); >0 = temperature sampling")
     p.add_argument("--cut", type=int, default=1,
                    help="split layer: client = embed + layers[:cut]")
+    p.add_argument("--layers", type=int, default=None,
+                   help="override num_layers after the preset (depth cut: "
+                        "full width, fewer layers)")
     p.add_argument("--no-backfill", action="store_true",
                    help="fixed-batch sequential baseline (drain barrier)")
     p.add_argument("--attn-impl", default="jnp", choices=["jnp", "flash"])
@@ -56,6 +63,7 @@ def main(argv=None):
                    help="record per-token latency events (repro.obs)")
     p.add_argument("--quiet", action="store_true")
     args = p.parse_args(argv)
+    enable_compile_cache()
 
     rec = None
     if args.metrics_dir:
@@ -64,7 +72,7 @@ def main(argv=None):
         obs.set_recorder(rec)
     obs.set_quiet(args.quiet)
     try:
-        _serve(args)
+        return _serve(args)
     finally:
         if rec is not None:
             rec.close()
@@ -73,6 +81,7 @@ def main(argv=None):
 
 
 def _serve(args):
+    """Runs the requests; returns the drained engine."""
     import jax
     import jax.numpy as jnp
 
@@ -84,6 +93,8 @@ def _serve(args):
     cfg = get_config(args.arch)
     if args.preset == "smoke":
         cfg = reduced_config(cfg)
+    if args.layers:
+        cfg = cfg.with_overrides(num_layers=args.layers)
     plan = lm.build_plan(cfg, args.cut)
     params = lm.init_lm(jax.random.key(args.seed), plan, jnp.float32)
     if args.checkpoint:
@@ -113,6 +124,7 @@ def _serve(args):
     obs.log("sample generations (token ids):")
     for c in completions[: min(4, len(completions))]:
         obs.log(f"   uid {c.uid}: {np.asarray(c.tokens)[:16].tolist()} ...")
+    return engine
 
 
 if __name__ == "__main__":

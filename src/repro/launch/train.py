@@ -59,33 +59,27 @@ from contextlib import contextmanager
 import numpy as np
 
 from repro import obs
+from repro.launch.compile_cache import enable_compile_cache
 
 
 @contextmanager
 def _maybe_profile(args, step: int):
     """``--profile N``: capture a ``jax.profiler`` trace of the one
     designated round/step (the trace of a single post-warmup step is
-    what you can actually read; tracing a whole run is noise)."""
+    what you can actually read; tracing a whole run is noise). A trace
+    that was asked for and cannot be taken fails the run."""
     if args.profile is None or step != args.profile:
         yield
         return
     import jax
 
     out = os.path.join(args.metrics_dir or ".", "profile")
-    try:
-        jax.profiler.start_trace(out)
-    except Exception as e:  # profiling is best-effort, never fatal
-        obs.log(f"profiler unavailable ({e}); skipping trace")
-        yield
-        return
+    jax.profiler.start_trace(out)
     try:
         yield
     finally:
-        try:
-            jax.profiler.stop_trace()
-            obs.log(f"profiler trace for step {step} -> {out}")
-        except Exception as e:
-            obs.log(f"profiler stop failed ({e})")
+        jax.profiler.stop_trace()
+    obs.log(f"profiler trace for step {step} -> {out}")
 
 
 def train_lm(args) -> dict:
@@ -1000,6 +994,7 @@ def main(argv=None):
                    help="suppress the stderr progress log (events still "
                         "recorded when --metrics-dir is set)")
     args = p.parse_args(argv)
+    enable_compile_cache()
     # recorder BEFORE any simulator/engine construction: instrumented
     # objects capture the active recorder when they are built
     rec = None
@@ -1010,9 +1005,8 @@ def main(argv=None):
     obs.set_quiet(args.quiet)
     try:
         if args.arch.startswith("paper-cnn"):
-            train_cnn(args)
-        else:
-            train_lm(args)
+            return train_cnn(args)
+        return train_lm(args)
     finally:
         if rec is not None:
             rec.close()

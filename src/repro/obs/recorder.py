@@ -82,14 +82,11 @@ def build_manifest(config: Optional[Dict] = None) -> Dict:
         "python": sys.version.split()[0],
         "platform": sys.platform,
     }
-    try:
-        import jax
+    import jax
 
-        man["jax_version"] = jax.__version__
-        man["backend"] = jax.default_backend()
-        man["device_count"] = jax.device_count()
-    except Exception:
-        man["jax_version"] = man["backend"] = None
+    man["jax_version"] = jax.__version__
+    man["backend"] = jax.default_backend()
+    man["device_count"] = jax.device_count()
     if config is not None:
         man["config"] = _json_safe(config)
         man["config_hash"] = config_hash(config)

@@ -297,6 +297,44 @@ class TestClientBankUnit:
         np.testing.assert_array_equal(np.asarray(dev.tree["w"]),
                                       np.asarray(sh.tree["w"]))
 
+    def test_sharded_rows_spread_over_devices(self):
+        """On four (virtual CPU) devices the sharded bank holds a quarter
+        of its rows on each, and a cross-shard gather/scatter round trip
+        still matches the device bank. Runs in a child process: the
+        device count is fixed when JAX starts."""
+        import os
+        import subprocess
+        import sys
+        import textwrap
+
+        script = textwrap.dedent("""
+            import jax, numpy as np
+            from repro.core.bank import ClientBank
+            assert len(jax.devices()) == 4
+            rng = np.random.RandomState(0)
+            t = {"w": rng.randn(8, 6).astype(np.float32),
+                 "b": rng.randn(8).astype(np.float32)}
+            dev = ClientBank(t, n_clients=8, stacked=True, backend="device")
+            sh = ClientBank(t, n_clients=8, stacked=True, backend="sharded")
+            per = sh.stats()["bytes_by_device"]
+            assert len(per) == 4, per
+            assert set(per.values()) == {sh.nbytes // 4}, per
+            assert dev.stats()["bytes_by_device"] == {
+                str(jax.devices()[0]): dev.nbytes}
+            idx = [1, 6]
+            dev.scatter(idx, jax.tree.map(lambda x: x * 2.0, dev.gather(idx)))
+            sh.scatter(idx, jax.tree.map(lambda x: x * 2.0, sh.gather(idx)))
+            np.testing.assert_array_equal(np.asarray(dev.tree["w"]),
+                                          np.asarray(sh.tree["w"]))
+            assert len(sh.tree["w"].sharding.device_set) == 4
+        """)
+        src = os.path.join(os.path.dirname(__file__), "..", "src")
+        env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=src,
+                   XLA_FLAGS="--xla_force_host_platform_device_count=4")
+        proc = subprocess.run([sys.executable, "-c", script], env=env,
+                              capture_output=True, text=True, timeout=300)
+        assert proc.returncode == 0, proc.stderr[-3000:]
+
 
 # --------------------------------------------------------- cyclic partition
 class TestCyclicPartition:

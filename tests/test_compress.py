@@ -220,14 +220,29 @@ class TestQuantizeKernels:
         np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                    atol=1e-5, rtol=1e-5)
 
-    def test_fused_path_approximates_exact_aggregate(self):
+    @pytest.mark.parametrize("bits", [8, 4])
+    def test_fused_path_approximates_exact_aggregate(self, bits):
+        # dequant(quantize(g)) against the unquantized eq. 5 reduction:
+        # stochastic rounding moves each value by under one step, and the
+        # ρ weights sum to 1, so the aggregate is within one step too
         g = jax.random.normal(KEY, (4, 256, 256), jnp.float32)
         rho = jnp.full((4,), 0.25)
-        q, s = ops.quantize(g, seed=3, bits=8)
-        fused = ops.dequant_agg(q, s, rho, bits=8)
+        q, s = ops.quantize(g, seed=3, bits=bits)
+        fused = ops.dequant_agg(q, s, rho, bits=bits)
         exact = ref.grad_agg_ref(g, rho)
-        scale = float(jnp.abs(g).max()) / 127
-        assert float(jnp.abs(fused - exact).max()) <= scale  # sum of ρ=1
+        step = float(jnp.abs(g).max()) / ((1 << (bits - 1)) - 1)
+        assert float(jnp.abs(fused - exact).max()) <= step
+
+    def test_codec_and_kernel_share_the_int4_wire_format(self):
+        # the codec's flat 256-element tiles are the kernel's (1, 256)
+        # tiles of a (1, T, 256) tensor: same words, same scales
+        g = jax.random.normal(KEY, (64, 256), jnp.float32)
+        p = IntQuantCodec(4, stochastic=False).encode(g)
+        q, s = ops.quantize(g[None], bits=4, backend="jnp", block_t=1,
+                            stochastic=False)
+        np.testing.assert_array_equal(np.asarray(p.data), np.asarray(q[0]))
+        np.testing.assert_array_equal(np.asarray(p.scale),
+                                      np.asarray(s[0, :, 0]))
 
     def test_int4_payload_is_half_the_bytes(self):
         g = jax.random.normal(KEY, (2, 256, 256), jnp.float32)

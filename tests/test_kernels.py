@@ -103,3 +103,24 @@ def test_grad_agg_dtypes():
         tol = 1e-2 if dt == jnp.bfloat16 else 1e-6
         np.testing.assert_allclose(np.asarray(out, np.float32),
                                    np.asarray(exp, np.float32), atol=tol)
+
+
+def test_kernel_import_initialises_no_backend():
+    """Importing the kernels picks no device: whether a kernel compiles
+    or is interpreted is decided when it is traced, so a process may
+    import them before it has chosen its backend."""
+    import os
+    import subprocess
+    import sys
+
+    script = (
+        "from jax._src import xla_bridge as xb\n"
+        "import repro.kernels.ops, repro.kernels.quantize, "
+        "repro.kernels.grad_agg\n"
+        "assert not xb._backends, sorted(xb._backends)\n")
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = dict(os.environ, PYTHONPATH=src)
+    proc = subprocess.run([sys.executable, "-c", script], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+

@@ -119,3 +119,46 @@ class TestCommModel:
         # saturation bound: r <= p*g/(N0 ln2)
         cap = p.client_power * g / (p.noise_psd * np.log(2))
         assert r1[0] <= cap * (1 + 1e-9)
+
+
+@pytest.mark.parametrize("from_env", [True, False])
+def test_compile_cache_placement(tmp_path, from_env):
+    """With ``JAX_COMPILATION_CACHE_DIR`` set, compiles land there and the
+    launchers set no other cache; without it, the cache is the fixed
+    ``<checkout>/.jax_cache``. A child process: JAX settles its cache once
+    per process."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    script = (
+        "import json, jax, jax.numpy as jnp\n"
+        "from repro.launch.compile_cache import (CHECKOUT_CACHE_DIR,\n"
+        "                                        enable_compile_cache)\n"
+        "where = enable_compile_cache()\n"
+        "if COMPILE:\n"
+        "    jax.jit(lambda x: jnp.sin(x) @ x.T)(jnp.ones((64, 64)))\n"
+        "print(json.dumps({'where': where,\n"
+        "                  'config': jax.config.jax_compilation_cache_dir,\n"
+        "                  'checkout': str(CHECKOUT_CACHE_DIR)}))\n"
+    ).replace("COMPILE", str(from_env))
+    src = os.path.join(os.path.dirname(__file__), "..", "src")
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    env.update(PYTHONPATH=src, JAX_PLATFORMS="cpu")
+    cache = tmp_path / "cache"
+    if from_env:
+        env.update(JAX_COMPILATION_CACHE_DIR=str(cache),
+                   JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0")
+    proc = subprocess.run([sys.executable, "-c", script], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    got = json.loads(proc.stdout.strip().splitlines()[-1])
+    if from_env:
+        assert got["where"] == got["config"] == str(cache)
+        assert any(cache.iterdir())
+    else:
+        assert got["where"] == got["config"] == got["checkout"]
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert got["checkout"] == os.path.join(root, ".jax_cache")
