@@ -38,6 +38,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.configs.base import ModelConfig, TrainConfig
 from repro.core.gradagg import uniform_rho
 from repro.core.protocol import ProtocolEngine, aggregate_cohort
@@ -230,9 +231,12 @@ def gather_cohort(tree: Dict, idx) -> Dict:
     ``tree`` is any params-shaped {client, server} dict — the params
     themselves or one optimizer moment. The server side is shared (O(1)
     in N) and passes through; client leaves lose their (N,) bank axis
-    for a (K,) cohort axis, ready for the jitted train step."""
-    jidx = jnp.asarray(idx)
-    return dict(tree, client=jax.tree.map(lambda x: x[jidx], tree["client"]))
+    for a (K,) cohort axis, ready for the jitted train step. Host span
+    ``bank/gather``: eager, each leaf's slice is its own program."""
+    with obs.get_recorder().span("bank/gather"):
+        jidx = jnp.asarray(idx)
+        return dict(tree,
+                    client=jax.tree.map(lambda x: x[jidx], tree["client"]))
 
 
 def scatter_cohort(bank: Dict, cohort: Dict, idx,
@@ -243,37 +247,42 @@ def scatter_cohort(bank: Dict, cohort: Dict, idx,
     resolve arbitrarily, each being an independent local update of the
     same client). ``broadcast_client=True`` writes cohort row 0 to EVERY
     bank row — the client-aggregating schemes (sfl), whose train step
-    already made all cohort rows the new global client model."""
-    if broadcast_client:
-        client = jax.tree.map(
-            lambda b, u: jnp.broadcast_to(u[0][None], b.shape).astype(b.dtype),
-            bank["client"], cohort["client"])
-    else:
-        jidx = jnp.asarray(idx)
-        client = jax.tree.map(lambda b, u: b.at[jidx].set(u),
-                              bank["client"], cohort["client"])
-    return dict(bank, client=client, server=cohort["server"])
+    already made all cohort rows the new global client model. Host span
+    ``bank/scatter``."""
+    with obs.get_recorder().span("bank/scatter"):
+        if broadcast_client:
+            client = jax.tree.map(
+                lambda b, u: jnp.broadcast_to(
+                    u[0][None], b.shape).astype(b.dtype),
+                bank["client"], cohort["client"])
+        else:
+            jidx = jnp.asarray(idx)
+            client = jax.tree.map(lambda b, u: b.at[jidx].set(u),
+                                  bank["client"], cohort["client"])
+        return dict(bank, client=client, server=cohort["server"])
 
 
 def gather_cohort_opt(opt_state: Dict, idx) -> Dict:
     """Cohort slice of the optimizer state: params-shaped moments (adamw
     m/v, momentum mu) gather like params; scalars (count) pass through."""
-    out = dict(opt_state)
-    for k in ("m", "v", "mu"):
-        if k in out:
-            out[k] = gather_cohort(out[k], idx)
-    return out
+    with obs.get_recorder().span("bank/gather"):
+        out = dict(opt_state)
+        for k in ("m", "v", "mu"):
+            if k in out:
+                out[k] = gather_cohort(out[k], idx)
+        return out
 
 
 def scatter_cohort_opt(bank_opt: Dict, cohort_opt: Dict, idx) -> Dict:
     """Inverse of ``gather_cohort_opt``. Moments always scatter per-row
     (each client keeps its OWN moment history even under sfl's parameter
     aggregation); scalars (count) come from the cohort run."""
-    out = dict(cohort_opt)
-    for k in ("m", "v", "mu"):
-        if k in out:
-            out[k] = scatter_cohort(bank_opt[k], cohort_opt[k], idx)
-    return out
+    with obs.get_recorder().span("bank/scatter"):
+        out = dict(cohort_opt)
+        for k in ("m", "v", "mu"):
+            if k in out:
+                out[k] = scatter_cohort(bank_opt[k], cohort_opt[k], idx)
+        return out
 
 
 def merge_lm_params(split: Dict, rho: Optional[jnp.ndarray] = None) -> Dict:

@@ -48,12 +48,15 @@ sums in chunk order — last-ulp divergence from the single-chunk result
 is possible at N > chunk_rows and documented in DESIGN.md §15.
 
 Instrumentation (``repro.obs``): the recorder active at construction is
-captured for the bank's lifetime. Gauges ``bank_gather_wait_s`` (how
-long the round blocked on the staged slice — ~0 when prefetch hid the
-copy), ``bank_prefetch_s`` / ``bank_scatter_s`` (worker-side copy
-times), counters ``bank_prefetch_hit`` / ``bank_prefetch_miss``, and
-``stats()`` for benchmarks: resident bytes, peak device bytes, hit
-rates. The fig11 acceptance bar reads ``device_bytes_peak``.
+captured for the bank's lifetime. Host spans, which are also profiler
+annotations (PERF.md §3): ``bank/gather`` and ``bank/scatter`` around the
+round path's calls, ``bank/wait`` while a round blocks on the staged slice
+(~0 when prefetch hid the copy), and on the worker thread
+``bank/prefetch`` (the host→device stage) and ``bank/drain`` (the
+device→host scatter). Counters ``bank_prefetch_hit`` /
+``bank_prefetch_miss``, and ``stats()`` for benchmarks: resident bytes,
+peak device bytes, hit rates, total gather wait. The fig11 acceptance bar
+reads ``device_bytes_peak``.
 """
 from __future__ import annotations
 
@@ -271,9 +274,13 @@ class ClientBank:
         """Device-resident cohort slice (leading axis K). ``idx=None`` is
         the identity cohort: the full bank (free on ``device``). On the
         ``host`` backend a staged prefetch for ``(t, idx)`` is consumed
-        when present (gauge ``bank_gather_wait_s`` records how long the
-        round still had to wait — ~0 when the overlap worked); otherwise
-        the slice is copied synchronously after a pipeline flush."""
+        when present (span ``bank/wait`` covers how long the round still
+        had to wait — ~0 when the overlap worked); otherwise the slice is
+        copied synchronously after a pipeline flush."""
+        with self._rec.span("bank/gather"):
+            return self._gather(idx, t)
+
+    def _gather(self, idx, t):
         if self.backend == "device":
             if idx is None:
                 return self._tree
@@ -292,11 +299,10 @@ class ClientBank:
                 and np.array_equal(staged[1], np.asarray(idx)):
             self._staged = None
             t0 = time.perf_counter()
-            out = staged[2].result()
-            wait = time.perf_counter() - t0
+            with self._rec.span("bank/wait"):
+                out = staged[2].result()
             self._hits += 1
-            self._gather_wait_s += wait
-            self._rec.gauge("bank_gather_wait_s", wait)
+            self._gather_wait_s += time.perf_counter() - t0
             self._rec.counter("bank_prefetch_hit")
             self._gathered_bytes = staged[3]
             self._staged_bytes = 0
@@ -329,10 +335,8 @@ class ClientBank:
         idx = np.asarray(idx)
 
         def stage():
-            t0 = time.perf_counter()
-            out = self._slice_to_device(idx)
-            self._rec.gauge("bank_prefetch_s", time.perf_counter() - t0)
-            return out
+            with self._rec.span("bank/prefetch"):
+                return self._slice_to_device(idx)
 
         fut = self._submit(stage)
         nbytes = sum(
@@ -354,6 +358,10 @@ class ClientBank:
         on every backend. Wholesale and broadcast scatters invalidate
         any staged prefetch — they rewrite every row, so the staged
         slice is stale regardless of cohort disjointness."""
+        with self._rec.span("bank/scatter"):
+            self._scatter(idx, updated, broadcast)
+
+    def _scatter(self, idx, updated, broadcast: bool) -> None:
         if self.backend in ("device", "sharded"):
             if broadcast:
                 new = jax.tree.map(
@@ -400,10 +408,9 @@ class ClientBank:
         upd_leaves, _treedef = jax.tree.flatten(updated)
 
         def drain():
-            t0 = time.perf_counter()
-            for b, u in zip(bank_leaves, upd_leaves):
-                b[idx] = np.asarray(u)  # blocks until the round computed u
-            self._rec.gauge("bank_scatter_s", time.perf_counter() - t0)
+            with self._rec.span("bank/drain"):
+                for b, u in zip(bank_leaves, upd_leaves):
+                    b[idx] = np.asarray(u)  # blocks until the round computed u
 
         self._submit(drain)
 

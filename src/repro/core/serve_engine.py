@@ -289,13 +289,15 @@ class ServeEngine:
         seed = jnp.asarray(
             (self.seed + 0x9E37 * (self.step_count + 1)) & 0x7FFFFFFF,
             jnp.uint32)
-        first, pcaches = self._prefill_fn(self.params, toks,
-                                          self._next_key(), seed)
+        with self._rec.span("serve/prefill"):
+            first, pcaches = self._prefill_fn(self.params, toks,
+                                              self._next_key(), seed)
         ids = np.zeros((self.max_pages,), np.int32)
         ids[:need] = pages
-        self.caches = self._adopt_fn(self.caches, pcaches,
-                                     jnp.asarray(slot, jnp.int32),
-                                     jnp.asarray(ids))
+        with self._rec.span("serve/adopt"):
+            self.caches = self._adopt_fn(self.caches, pcaches,
+                                         jnp.asarray(slot, jnp.int32),
+                                         jnp.asarray(ids))
         first = int(first)
         self._pending_prefill_lens.append(S)
         comp = Completion(uid=req.uid, prompt_len=S,
@@ -369,10 +371,16 @@ class ServeEngine:
     def step(self) -> Dict[str, float]:
         """Admit → decode one token for every live slot → retire.
 
-        Returns per-step stats (also emitted as a ``serve_token`` event).
+        Returns per-step stats (also emitted as a ``serve_token`` event
+        when the recorder is enabled). Host spans, also profiler
+        annotations: ``serve/admit`` (``serve/prefill`` and
+        ``serve/adopt`` per admission), ``serve/tables``,
+        ``serve/decode`` (dispatch up to the tokens' read-back) and
+        ``serve/retire``.
         """
         rec = self._rec
-        admitted = self._admit_from_queue()
+        with rec.span("serve/admit"):
+            admitted = self._admit_from_queue()
         prefill_lens = self._pending_prefill_lens
         self._pending_prefill_lens = []
         self._ensure_capacity()
@@ -381,8 +389,9 @@ class ServeEngine:
             return {"n_live": 0, "admitted": admitted, "retired": 0,
                     "latency_s": 0.0}
         if self._dirty:
-            self.caches = self._tables_fn(
-                self.caches, self._table, self._lengths, self._live)
+            with rec.span("serve/tables"):
+                self.caches = self._tables_fn(
+                    self.caches, self._table, self._lengths, self._live)
             self._dirty = False
 
         live_before = self._live.copy()
@@ -390,13 +399,17 @@ class ServeEngine:
         seed = jnp.asarray(
             (self.seed ^ 0x51E9 * (self.step_count + 1)) & 0x7FFFFFFF,
             jnp.uint32)
-        t0 = time.perf_counter()
-        nxt, self.caches = self._step_fn(
-            self.params, self.caches, jnp.asarray(self._cur_tok),
-            jnp.asarray(live_before), self._next_key(), seed)
-        nxt = np.asarray(nxt)  # per-token latency needs a per-step sync
-        step_s = time.perf_counter() - t0
+        with rec.span("serve/decode"):
+            t0 = time.perf_counter()
+            nxt, self.caches = self._step_fn(
+                self.params, self.caches, jnp.asarray(self._cur_tok),
+                jnp.asarray(live_before), self._next_key(), seed)
+            nxt = np.asarray(nxt)  # per-token latency needs a per-step sync
+            step_s = time.perf_counter() - t0
         self.step_latencies_s.append(step_s)
+        # the caches' lengths as the step left them: a live slot wrote one
+        # token (retirement below zeroes the host's row before the next push)
+        written = self._lengths + live_before
 
         # modeled vs measured decode+prefill traffic, reconciled exactly
         self._flush_traffic(n_live, prefill_lens)
@@ -410,27 +423,29 @@ class ServeEngine:
 
         retired = 0
         ci = 0
-        for slot in range(self.slots):
-            if not live_before[slot]:
-                continue
-            tok = int(nxt[slot])
-            meta = self._slot_meta[slot]
-            meta.completion.tokens.append(tok)
-            tok_s = step_s + float(comm_s[ci])
-            meta.completion.token_latencies_s.append(tok_s)
-            if slo_s is None or tok_s <= slo_s:
-                meta.completion.slo_hits += 1
-            ci += 1
-            self._lengths[slot] += 1
-            self._cur_tok[slot] = tok
-            if self._maybe_finish(slot, tok):
-                retired += 1
+        with rec.span("serve/retire"):
+            for slot in range(self.slots):
+                if not live_before[slot]:
+                    continue
+                tok = int(nxt[slot])
+                meta = self._slot_meta[slot]
+                meta.completion.tokens.append(tok)
+                tok_s = step_s + float(comm_s[ci])
+                meta.completion.token_latencies_s.append(tok_s)
+                if slo_s is None or tok_s <= slo_s:
+                    meta.completion.slo_hits += 1
+                ci += 1
+                self._lengths[slot] += 1
+                self._cur_tok[slot] = tok
+                if self._maybe_finish(slot, tok):
+                    retired += 1
         self.step_count += 1
 
-        rec.event("serve_token", name="decode", model=self.cfg.name,
-                  step=self.step_count - 1, batch=n_live, latency_s=step_s,
-                  admitted=admitted, retired=retired,
-                  **paging.paged_cache_stats(self.caches))
+        if rec.enabled:
+            rec.event("serve_token", name="decode", model=self.cfg.name,
+                      step=self.step_count - 1, batch=n_live,
+                      latency_s=step_s, admitted=admitted, retired=retired,
+                      **paging.paged_cache_stats(self.caches, written))
         return {"n_live": n_live, "admitted": admitted, "retired": retired,
                 "latency_s": step_s}
 

@@ -288,9 +288,12 @@ def replace_tables(caches, page_table: np.ndarray, lengths: np.ndarray,
     return map_paged(caches, upd)
 
 
-def paged_cache_stats(caches) -> dict:
-    """Live-token / page occupancy summary for obs (`serve` events)."""
+def paged_cache_stats(caches, lengths) -> dict:
+    """Live-token / page occupancy summary for obs (`serve` events).
+    ``lengths`` is the host's copy of the caches' per-slot lengths; of the
+    caches only shapes are read, so nothing waits on the device."""
     pages = tokens = pools = 0
+    lens = np.asarray(lengths)
     for gc in caches:
         for c in gc:
             if not isinstance(c, PagedKVCache):
@@ -298,7 +301,6 @@ def paged_cache_stats(caches) -> dict:
             # stacked layout: pages_k (L, Hkv, P, page, D), lengths (L, slots)
             L = c.page_table.shape[0]
             page = int(c.pages_k.shape[3])
-            lens = np.asarray(c.lengths[0])
             tokens += int(lens.sum()) * L
             pages += sum(pages_for(int(t), page) for t in lens) * L
             pools += int(c.pages_k.shape[2]) * L

@@ -8,9 +8,16 @@ twice: the disabled path is the pre-obs code path, bit for bit (no
 ``jax.debug.callback`` is ever staged into a jit graph unless metrics
 are on, so jitted round bodies are untouched).
 
+Spans are the one exception to "no-op": under either recorder a span is a
+``jax.profiler.TraceAnnotation`` of the same name, which records only while
+a profiler trace runs. Then the program's spans (``bank/gather``,
+``serve/decode`` …) lie on the device trace's clock, with nothing to switch
+on, and the benchmark's trace reduction reads them (PERF.md §3).
+
 Three layers:
 
-* **recorder** — counters / gauges / events + wall-clock spans, buffered
+* **recorder** — counters / gauges / events + wall-clock spans (also
+  profiler annotations), buffered
   to a JSONL event sink with a per-run ``manifest.json`` (config hash,
   git SHA, backend, jax version). ``emit_from_jit`` is the
   ``jax.debug.callback`` emit path for values produced inside jitted

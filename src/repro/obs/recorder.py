@@ -22,6 +22,14 @@ summary    final counter totals, written on close
 The recorder is deliberately host-side and lock-protected: the
 ``jax.debug.callback`` emit path (``emit_from_jit``, plus the traffic
 ledger's taps) runs on the runtime's callback thread.
+
+Every span, from the real recorder and from :class:`NullRecorder` alike,
+is also a ``jax.profiler.TraceAnnotation`` under the span's own name: while
+a profiler trace runs (``--profile N``, the benchmark's ``--trace 1``), the
+span lies on the host plane on the device trace's clock, so device idle time
+can be laid against it. With no trace active the annotation costs about what
+a ``contextlib.nullcontext`` does. Spans measure host time only; nothing is
+staged into a jitted function.
 """
 from __future__ import annotations
 
@@ -34,6 +42,8 @@ import sys
 import threading
 import time
 from typing import Any, Dict, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.obs.ledger import TrafficLedger
 
@@ -93,23 +103,11 @@ def build_manifest(config: Optional[Dict] = None) -> Dict:
     return man
 
 
-class _NullSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NULL_SPAN = _NullSpan()
-
-
 class NullRecorder:
     """The disabled default: every method a no-op (the hot path pays one
-    attribute load + truthiness check at most). Keeps the stderr text
-    sink so ``obs.log`` works metrics-off too."""
+    attribute load + truthiness check at most), except that a span is still
+    a profiler annotation, which records only while a trace runs. Keeps the
+    stderr text sink so ``obs.log`` works metrics-off too."""
 
     enabled = False
     ledger = None
@@ -122,7 +120,7 @@ class NullRecorder:
         pass
 
     def span(self, name, **attrs):
-        return _NULL_SPAN
+        return TraceAnnotation(name, **attrs)
 
     def counter(self, name, value=1, **attrs):
         pass
@@ -152,22 +150,27 @@ null_recorder = NullRecorder()
 
 
 class _Span:
-    __slots__ = ("rec", "name", "attrs", "t0", "parent", "depth")
+    """A JSONL span record and, for its lifetime, a profiler annotation."""
+
+    __slots__ = ("rec", "name", "attrs", "t0", "parent", "depth", "ann")
 
     def __init__(self, rec, name, attrs):
         self.rec, self.name, self.attrs = rec, name, attrs
+        self.ann = TraceAnnotation(name, **attrs)
 
     def __enter__(self):
-        st = self.rec._span_stack
+        st = self.rec._span_stack()
         self.parent = st[-1] if st else None
         self.depth = len(st)
         st.append(self.name)
+        self.ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         dur = time.perf_counter() - self.t0
-        self.rec._span_stack.pop()
+        self.ann.__exit__(*exc)
+        self.rec._span_stack().pop()
         self.rec._emit(dict(kind="span", name=self.name, dur_s=dur,
                             depth=self.depth, parent=self.parent,
                             **self.attrs))
@@ -201,7 +204,7 @@ class Recorder:
         self._flush_every = max(1, flush_every)
         self._seq = 0
         self._round = None
-        self._span_stack = []
+        self._spans = threading.local()  # open spans, per thread
         self._counters: Dict[str, float] = {}
         self._fh = None
         self.manifest = build_manifest(config)
@@ -223,6 +226,14 @@ class Recorder:
     @property
     def round(self) -> Optional[int]:
         return self._round
+
+    def _span_stack(self) -> list:
+        """Names of the spans open on the calling thread (a bank worker's
+        spans nest under nothing of the main thread's)."""
+        st = getattr(self._spans, "stack", None)
+        if st is None:
+            st = self._spans.stack = []
+        return st
 
     # ------------------------------------------------------------------
     def _emit(self, rec: Dict) -> None:

@@ -18,6 +18,7 @@ import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
 
 from repro import obs  # noqa: E402
 from repro.configs.paper_cnn import LIGHT_CONFIG  # noqa: E402
@@ -353,3 +354,220 @@ class TestReport:
 
     def test_report_missing_dir(self, capsys):
         assert report_mod.main(["/nonexistent/run"]) == 2
+
+
+# ------------------------------------------------- spans on the profiler
+def _host_events(tmp_path, fn):
+    """Run ``fn`` under a CPU profiler trace; the ``/host:CPU`` events as
+    (line, name, start_ns, end_ns)."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "/host:CPU":
+            for line in plane.lines:
+                out += [(line.name, e.name, e.start_ns,
+                         e.start_ns + e.duration_ns) for e in line.events]
+    return out
+
+
+def _only(events, name):
+    (ev,) = [e for e in events if e[1] == name]
+    return ev
+
+
+def _lm_setup(n=3):
+    from repro.configs import TrainConfig, get_config, reduced_config
+    from repro.core import algorithms as alg
+    from repro.models import lm
+    from repro.optim import make_optimizer
+
+    cfg = reduced_config(get_config("granite-8b")).with_overrides(
+        num_layers=2, d_model=64, d_ff=128, vocab_size=256,
+        num_heads=2, num_kv_heads=1, head_dim=32)
+    plan = lm.build_plan(cfg, 1)
+    params = alg.split_lm_params(
+        lm.init_lm(jax.random.key(0), plan, jnp.float32), n)
+    tcfg = TrainConfig(model=cfg, algo="sfl_ga", cut_layer=1,
+                       compute_dtype="float32", remat=False)
+    opt = make_optimizer("adamw", 1e-3)
+    return alg, plan, tcfg, opt, params
+
+
+class TestProfilerSpans:
+    @pytest.mark.parametrize("real", [False, True], ids=["null", "recorder"])
+    def test_spans_reach_the_trace_nested(self, tmp_path, real):
+        rec = Recorder() if real else obs.null_recorder
+
+        def body():
+            with rec.span("obs-test/outer", cut=2):
+                with rec.span("obs-test/inner"):
+                    time.sleep(0.002)
+
+        evs = _host_events(tmp_path, body)
+        outer = _only(evs, "obs-test/outer")
+        inner = _only(evs, "obs-test/inner")
+        assert outer[0] == inner[0]  # one thread's line
+        assert outer[2] <= inner[2] and inner[3] <= outer[3]
+        assert inner[3] - inner[2] >= 2_000_000
+
+    def test_recorder_span_records_are_unchanged(self):
+        import threading
+
+        rec = Recorder()
+        with rec.span("outer", cut=2):
+            worker = threading.Thread(
+                target=lambda: rec.span("worker").__enter__().__exit__(
+                    None, None, None))
+            worker.start()
+            worker.join(timeout=10)
+            assert not worker.is_alive()
+            with rec.span("inner"):
+                pass
+        spans = [e for e in rec.events if e["kind"] == "span"]
+        assert [e["name"] for e in spans] == ["worker", "inner", "outer"]
+        base = {"kind", "name", "dur_s", "depth", "parent", "round", "seq",
+                "ts"}
+        assert set(spans[1]) == base and set(spans[2]) == base | {"cut"}
+        # a worker thread's span nests under nothing of the main thread's
+        assert (spans[0]["depth"], spans[0]["parent"]) == (0, None)
+        assert (spans[1]["depth"], spans[1]["parent"]) == (1, "outer")
+
+    def test_cohort_gather_and_scatter_spans(self, tmp_path):
+        alg, _, _, opt, params = _lm_setup()
+        opt_state = opt.init(params)
+        idx = np.asarray([0, 2])
+
+        def body():
+            cp = alg.gather_cohort(params, idx)
+            alg.scatter_cohort(params, cp, idx)
+            cop = alg.gather_cohort_opt(opt_state, idx)
+            alg.scatter_cohort_opt(opt_state, cop, idx)
+
+        names = [e[1] for e in _host_events(tmp_path, body)]
+        # the _opt forms nest one span per moment inside their own
+        assert names.count("bank/gather") == 1 + 1 + 2
+        assert names.count("bank/scatter") == 1 + 1 + 2
+        rec = Recorder()
+        with obs.use_recorder(rec):
+            body()
+        spans = [e for e in rec.events if e["kind"] == "span"]
+        assert {e["name"] for e in spans} == {"bank/gather", "bank/scatter"}
+        assert {e["parent"] for e in spans if e["depth"] == 1} == {
+            "bank/gather", "bank/scatter"}
+
+    def test_client_bank_spans_replace_its_gauges(self):
+        from repro.core.bank import ClientBank
+
+        rec = Recorder()
+        tree = {"w": np.arange(24, dtype=np.float32).reshape(6, 4)}
+        want = tree["w"][[1, 3]] + 1.0  # the host bank keeps ``tree``'s rows
+        with obs.use_recorder(rec):
+            bank = ClientBank(tree, n_clients=6, stacked=True,
+                              backend="host")
+        with bank:
+            bank.prefetch(0, [1, 3])
+            cohort = bank.gather([1, 3], t=0)
+            bank.scatter([1, 3], {"w": cohort["w"] + 1.0})
+            bank.flush()
+        names = {e["name"] for e in rec.events if e["kind"] == "span"}
+        assert names == {"bank/prefetch", "bank/wait", "bank/gather",
+                         "bank/drain", "bank/scatter"}
+        assert not [e for e in rec.events if e["kind"] == "gauge"]
+        assert bank.stats()["prefetch_hits"] == 1
+        np.testing.assert_array_equal(bank.tree["w"][[1, 3]], want)
+
+    def test_train_step_is_untouched_by_tracing(self, tmp_path):
+        """gather → step → scatter inside a profiler trace gives the same
+        bits as outside one, and the step's program holds no host
+        callback: the spans stage nothing into jitted code."""
+        alg, plan, tcfg, opt, params = _lm_setup()
+        step = jax.jit(alg.make_train_step(plan, tcfg, opt, 2))
+        rng = np.random.RandomState(0)
+        batch = {"tokens": jnp.asarray(rng.randint(0, 256, (2, 2, 16))),
+                 "labels": jnp.asarray(rng.randint(0, 256, (2, 2, 16))),
+                 "rho": jnp.asarray([0.5, 0.5])}
+        idx = np.asarray([0, 2])
+
+        def one():
+            opt_state = opt.init(params)
+            cp, cop, m = step(alg.gather_cohort(params, idx),
+                              alg.gather_cohort_opt(opt_state, idx), batch)
+            new = alg.scatter_cohort(params, cp, idx)
+            return jax.tree.map(np.asarray, (new, m["loss"]))
+
+        outside = one()
+        inside = []
+        _host_events(tmp_path, lambda: inside.append(one()))
+        for a, b in zip(jax.tree.leaves(outside), jax.tree.leaves(inside[0])):
+            np.testing.assert_array_equal(a, b)
+        text = step.lower(alg.gather_cohort(params, idx),
+                          alg.gather_cohort_opt(opt.init(params), idx),
+                          batch).as_text()
+        assert "callback" not in text
+
+    def test_serve_token_stats_without_a_device_read(self, monkeypatch):
+        """The serve_token occupancy comes from the host's lengths: equal,
+        step by step and through retirements, to what the caches' own
+        lengths give; with no recorder the stats are not built at all."""
+        from repro.configs import get_config, reduced_config
+        from repro.core.serve_engine import ServeEngine, make_requests
+        from repro.models import lm, paging
+
+        cfg = reduced_config(get_config("granite-8b"))
+        plan = lm.build_plan(cfg, 1)
+        params = lm.init_lm(jax.random.key(0), plan, jnp.float32)
+
+        def device_stats(caches):  # the device read the engine no longer makes
+            toks = pages = 0
+            for gc in caches:
+                for c in gc:
+                    if isinstance(c, paging.PagedKVCache):
+                        L, page = c.page_table.shape[0], c.pages_k.shape[3]
+                        lens = np.asarray(c.lengths[0])
+                        toks += int(lens.sum()) * L
+                        pages += sum(paging.pages_for(int(t), page)
+                                     for t in lens) * L
+            return toks, pages
+
+        rec = Recorder()
+        with obs.use_recorder(rec):
+            eng = ServeEngine(params, plan, slots=3, max_len=24,
+                              page_size=8, seed=0)
+        for r in make_requests(5, 7, [4, 2, 10], vocab_size=cfg.vocab_size):
+            eng.submit(r)
+        seen = retired = 0
+        while eng.queue or eng._live.any():
+            eng.step()
+            evs = [e for e in rec.events if e["kind"] == "serve_token"]
+            if len(evs) == seen:
+                continue
+            seen = len(evs)
+            retired += evs[-1]["retired"]
+            assert (evs[-1]["live_tokens"], evs[-1]["pages_in_use"]) == \
+                device_stats(eng.caches)
+        assert retired == 5 and seen > 3
+        spans = {e["name"] for e in rec.events if e["kind"] == "span"}
+        assert spans == {"serve/admit", "serve/prefill", "serve/adopt",
+                         "serve/tables", "serve/decode", "serve/retire"}
+        null_eng = ServeEngine(params, plan, slots=3, max_len=24,
+                               page_size=8, seed=0)
+        assert not null_eng._rec.enabled
+
+        def refuse(*a):
+            raise AssertionError("occupancy built with no recorder")
+
+        monkeypatch.setattr(paging, "paged_cache_stats", refuse)
+        null_eng.submit(make_requests(1, 7, 2, vocab_size=cfg.vocab_size)[0])
+        null_eng.run()
+        assert len(null_eng.completions) == 1
