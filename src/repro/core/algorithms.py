@@ -225,6 +225,27 @@ def resplit_opt_state(opt_state: Dict, old_plan: lm_mod.ModelPlan,
     return out
 
 
+# One compiled program per cohort gather / scatter (DESIGN.md §13): the
+# per-leaf expressions under one jit, with ``idx`` traced so every cohort
+# of the same size K reuses the program. No donation: callers keep the
+# old bank after a scatter.
+@jax.jit
+def _gather_rows(tree, idx):
+    return jax.tree.map(lambda x: x[idx], tree)
+
+
+@jax.jit
+def _scatter_rows(bank, cohort, idx):
+    return jax.tree.map(lambda b, u: b.at[idx].set(u), bank, cohort)
+
+
+@jax.jit
+def _broadcast_row0(bank, cohort):
+    return jax.tree.map(
+        lambda b, u: jnp.broadcast_to(u[0][None], b.shape).astype(b.dtype),
+        bank, cohort)
+
+
 def gather_cohort(tree: Dict, idx) -> Dict:
     """Slice the client bank to the round's cohort rows (DESIGN.md §13).
 
@@ -232,11 +253,10 @@ def gather_cohort(tree: Dict, idx) -> Dict:
     themselves or one optimizer moment. The server side is shared (O(1)
     in N) and passes through; client leaves lose their (N,) bank axis
     for a (K,) cohort axis, ready for the jitted train step. Host span
-    ``bank/gather``: eager, each leaf's slice is its own program."""
+    ``bank/gather``: one compiled program for the whole client tree."""
     with obs.get_recorder().span("bank/gather"):
-        jidx = jnp.asarray(idx)
         return dict(tree,
-                    client=jax.tree.map(lambda x: x[jidx], tree["client"]))
+                    client=_gather_rows(tree["client"], jnp.asarray(idx)))
 
 
 def scatter_cohort(bank: Dict, cohort: Dict, idx,
@@ -248,17 +268,13 @@ def scatter_cohort(bank: Dict, cohort: Dict, idx,
     same client). ``broadcast_client=True`` writes cohort row 0 to EVERY
     bank row — the client-aggregating schemes (sfl), whose train step
     already made all cohort rows the new global client model. Host span
-    ``bank/scatter``."""
+    ``bank/scatter``: one compiled program for the whole client tree."""
     with obs.get_recorder().span("bank/scatter"):
         if broadcast_client:
-            client = jax.tree.map(
-                lambda b, u: jnp.broadcast_to(
-                    u[0][None], b.shape).astype(b.dtype),
-                bank["client"], cohort["client"])
+            client = _broadcast_row0(bank["client"], cohort["client"])
         else:
-            jidx = jnp.asarray(idx)
-            client = jax.tree.map(lambda b, u: b.at[jidx].set(u),
-                                  bank["client"], cohort["client"])
+            client = _scatter_rows(bank["client"], cohort["client"],
+                                   jnp.asarray(idx))
         return dict(bank, client=client, server=cohort["server"])
 
 

@@ -530,6 +530,102 @@ class TestLMCohort:
                         jax.tree.leaves(back_opt)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
+    @staticmethod
+    def _eager_gather(tree, idx):
+        jidx = jnp.asarray(idx)
+        return dict(tree, client=jax.tree.map(lambda x: x[jidx],
+                                              tree["client"]))
+
+    @staticmethod
+    def _eager_scatter(bank, cohort, idx, broadcast=False):
+        jidx = jnp.asarray(idx)
+        if broadcast:
+            client = jax.tree.map(
+                lambda b, u: jnp.broadcast_to(
+                    u[0][None], b.shape).astype(b.dtype),
+                bank["client"], cohort["client"])
+        else:
+            client = jax.tree.map(lambda b, u: b.at[jidx].set(u),
+                                  bank["client"], cohort["client"])
+        return dict(bank, client=client, server=cohort["server"])
+
+    @pytest.mark.parametrize("move", ["gather", "scatter", "gather_opt",
+                                      "scatter_opt", "broadcast"])
+    def test_cohort_moves_match_eager_expressions(self, move):
+        """Each compiled gather/scatter gives the per-leaf eager
+        expressions' trees bit for bit, on an unsorted cohort."""
+        from repro.core import algorithms as alg
+
+        _, _, _, opt, params = self._setup(n=5)
+        idx = np.asarray([3, 0, 4])
+
+        def distinct(tree):  # distinct rows, so a misplaced row shows
+            return jax.tree.map(
+                lambda x: x + jnp.arange(x.size, dtype=x.dtype).reshape(
+                    x.shape) * 1e-3, tree)
+
+        opt_state = opt.init(params)
+        opt_state = dict(opt_state, m=distinct(opt_state["m"]),
+                         v=distinct(opt_state["v"]))
+        params = distinct(params)
+
+        def bump(tree):
+            return jax.tree.map(lambda x: x * 2.0 + 1.0, tree)
+
+        if move == "gather":
+            got = alg.gather_cohort(params, idx)
+            want = self._eager_gather(params, idx)
+        elif move == "gather_opt":
+            got = alg.gather_cohort_opt(opt_state, idx)
+            want = dict(opt_state, **{k: self._eager_gather(opt_state[k], idx)
+                                      for k in ("m", "v")})
+        elif move == "scatter_opt":
+            cohort = dict(opt_state, **{
+                k: bump(self._eager_gather(opt_state[k], idx))
+                for k in ("m", "v")})
+            got = alg.scatter_cohort_opt(opt_state, cohort, idx)
+            want = dict(cohort, **{
+                k: self._eager_scatter(opt_state[k], cohort[k], idx)
+                for k in ("m", "v")})
+        else:
+            broadcast = move == "broadcast"
+            cohort = bump(self._eager_gather(params, idx))
+            got = alg.scatter_cohort(params, cohort, idx,
+                                     broadcast_client=broadcast)
+            want = self._eager_scatter(params, cohort, idx, broadcast)
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert a.dtype == b.dtype
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        if move in ("gather", "scatter", "broadcast"):
+            # the shared server side passes through untouched
+            assert got["server"] is (params if move == "gather"
+                                     else cohort)["server"]
+
+    @pytest.mark.parametrize("helper", ["_gather_rows", "_scatter_rows",
+                                        "_broadcast_row0"])
+    def test_cohort_programs_compile_once_per_k(self, helper):
+        """The cohort index is traced: cohorts of one size K share one
+        program, and another K adds exactly one."""
+        from repro.core import algorithms as alg
+
+        _, _, _, _, params = self._setup(n=5)
+        fn = getattr(alg, helper)
+        fn.clear_cache()
+
+        def move(idx):
+            cohort = alg.gather_cohort(params, idx)
+            if helper == "_gather_rows":
+                return
+            alg.scatter_cohort(params, cohort, idx,
+                               broadcast_client=helper == "_broadcast_row0")
+
+        for idx in ([0, 1, 2], [4, 2, 3], [3, 0, 1]):
+            move(np.asarray(idx))
+        assert fn._cache_size() == 1
+        move(np.asarray([1, 4]))
+        assert fn._cache_size() == 2
+
     def test_partial_step_leaves_nonparticipants(self):
         from repro.core import algorithms as alg
 
