@@ -46,7 +46,8 @@ def run(cell, keep=None) -> dict:
     plan = lm.build_plan(cfg, cfgj["cut"])
     dtype = jnp.dtype(cfgj["dtype"])
     params = weights.make(jax.eval_shape(
-        lambda k: lm.init_lm(k, plan, dtype), jax.random.key(0)), seed)
+        lambda k: lm.init_lm(k, plan, dtype), jax.random.key(0)), seed,
+        ssm=cfg.ssm)
     engine_seed = int(generate.rng(seed, "order").integers(0, 2**31))
     engine = ServeEngine(params, plan, slots=tj["slots"],
                          max_len=tj["max_len"], page_size=tj["page_size"],

@@ -2,12 +2,17 @@
 
 Per step, as ``repro.launch.train`` runs it: the harness's batch →
 ``sampler.cohort`` → ``gather_cohort``/``gather_cohort_opt`` → the jitted
-``make_train_step`` → ``scatter_cohort``/``scatter_cohort_opt`` → the loss
-read back to the host, which ends the step.
+``make_train_step`` → ``scatter_cohort``/``scatter_cohort_opt``; the step's
+loss is read back to the host.
 
 Set-up builds the step and its state once and drives its first
-``check_steps`` steps through that same loop (they compile it); the window
-goes on from there with the same objects. After the window the program's
+``check_steps`` steps through that same loop (they compile it), reading
+each loss as its step ends; the window goes on from there with the same
+objects. In the window the host dispatches up to ``AHEAD_S`` seconds of
+steps ahead of the loss it reads, so that a stall of the host does not
+idle the device; when the time is up it dispatches nothing more, reads
+every loss still due, and only then reads the clock: every dispatched step
+counts, over all of that time. After the window the program's
 state is dropped and the plain reference replays those first steps from the
 same weights and batches: each step's loss, the first gradient as SGD got
 it (from the parameters after step 1) and the change after the last are
@@ -15,6 +20,7 @@ compared leaf by leaf.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import sys
 import time
@@ -23,23 +29,41 @@ import numpy as np
 
 from chipbench import flops, generate, weights
 
+# seconds of steps in flight ahead of the loss the window's loop reads
+AHEAD_S = 6.0
+
+
+def _tuple(v):
+    return tuple(v) if isinstance(v, list) else v
+
 
 def model_config(config: dict):
-    from repro.configs.base import ModelConfig
+    """The program's ``ModelConfig`` from a configuration file: the keys it
+    has a field for (the harness's own, such as ``cut``, stay out), nested
+    ``moe``/``ssm`` objects as their dataclasses and lists as tuples, so
+    that the result is hashable."""
+    from repro.configs.base import ModelConfig, MoEConfig, SSMConfig
 
+    nested = {"moe": MoEConfig, "ssm": SSMConfig}
     keys = {f.name for f in dataclasses.fields(ModelConfig)}
-    return ModelConfig(**{k: v for k, v in config.items() if k in keys})
+    kw = {k: _tuple(v) for k, v in config.items() if k in keys}
+    for k, cls in nested.items():
+        if kw.get(k) is not None:
+            kw[k] = cls(**{f: _tuple(v) for f, v in kw[k].items()})
+    return ModelConfig(**kw)
+
+
+# what a configuration file says about itself, not about the model
+DESCRIPTIVE = ("name", "source", "published", "reduced", "assumed",
+               "deployment", "reference")
 
 
 def reference_config(config: dict, traffic: dict) -> dict:
-    out = {k: config[k] for k in (
-        "num_layers", "d_model", "num_heads", "num_kv_heads", "head_dim",
-        "d_ff", "vocab_size", "rope_theta", "mlp_act", "norm_eps", "cut")}
-    if "page_size" in traffic:
-        out["page_size"] = traffic["page_size"]
-    if "lora_rank" in traffic:
-        out["lora_alpha"] = traffic["lora_alpha"]
-        out["lora_rank"] = traffic["lora_rank"]
+    """Every architecture key of the configuration, nested objects
+    included, and the traffic's LoRA and page keys."""
+    out = {k: v for k, v in config.items() if k not in DESCRIPTIVE}
+    out.update({k: traffic[k] for k in ("page_size", "lora_rank",
+                                        "lora_alpha") if k in traffic})
     return out
 
 
@@ -92,6 +116,7 @@ class Setup:
         from repro.core import algorithms as alg
         from repro.core.cohort import make_sampler
         from repro.models import lm
+        from repro.models.transformer import layer_specs
         from repro.optim import make_optimizer
 
         cfgj, tj, seed = cell.config, cell.traffic, cell.seed
@@ -117,7 +142,8 @@ class Setup:
             return alg.split_lm_lora_params(base, loras, N)
 
         self.params = weights.make(jax.eval_shape(init, jax.random.key(0)),
-                                   seed, alpha, rank, adapters=True)
+                                   seed, alpha, rank, adapters=True,
+                                   ssm=cfg.ssm)
         self.base = self.params["base"]
         opt = make_optimizer(tj["optimizer"], lr)
         self.opt_state = opt.init(alg.trainable_params(self.params))
@@ -126,18 +152,23 @@ class Setup:
         self.step_fn = jax.jit(alg.make_train_step(plan, tcfg, opt, K))
         self.codec_seed0 = int(generate.rng(seed, "order").integers(0, 2**32))
         self.shape, self.vocab = (K, b, S), cfg.vocab_size
-        self.flops = flops.train_step_flops(cfgj, cohort=K, batch=b, seq=S,
+        self.flops = flops.train_step_flops(cfgj, kinds=layer_specs(cfg),
+                                            cohort=K, batch=b, seq=S,
                                             rank=rank)
         # the first steps, through the window's own loop
         self.snaps = [self.trainable()]
-        self.losses, self.fed = [], []
+        self.losses, self.fed, took = [], [], []
         n_check = tj["check_steps"]
         for i in range(n_check):
+            t = time.perf_counter()
             loss, bt = self.one(i)
-            self.losses.append(loss)
+            self.losses.append(float(loss))
+            took.append(time.perf_counter() - t)
             self.fed.append(bt)
             if i == 0 or i == n_check - 1:
                 self.snaps.append(self.trainable())
+        # steps in flight in the window: AHEAD_S over the fastest warm step
+        self.ahead = max(1, round(AHEAD_S / min(took[1:] or took)))
 
     def trainable(self):
         return _host(self.alg.trainable_params(self.params))
@@ -150,7 +181,8 @@ class Setup:
                                   & 0xFFFFFFFF)}
 
     def one(self, i):
-        """One step of the launcher's loop; returns (loss, what it fed)."""
+        """One step of the launcher's loop, dispatched; returns (the loss on
+        the device, what it fed)."""
         import jax.numpy as jnp
 
         alg, spans = self.alg, self.cell.spans
@@ -168,9 +200,7 @@ class Setup:
             self.params = alg.scatter_cohort(self.params, cp, idx,
                                              broadcast_client=False)
             self.opt_state = alg.scatter_cohort_opt(self.opt_state, cop, idx)
-        with spans("step"):
-            loss = float(m["loss"])
-        return loss, dict(bt, idx=np.asarray(idx), w=np.asarray(w))
+        return m["loss"], dict(bt, idx=np.asarray(idx), w=np.asarray(w))
 
     def drop(self):
         """Free the program's state; the weights the benchmark made stay."""
@@ -190,19 +220,27 @@ def run(cell) -> dict:
     t0 = time.perf_counter()
     setup_s = t0 - cell.t_process
     n, failed, first = 0, 0, len(st.fed)
+    due = collections.deque()
+
+    def read(loss):
+        with cell.spans("loss read"):
+            return int(not np.isfinite(float(loss)))
+
     with cell.spans("window"):
-        while True:
-            loss, _ = st.one(first + n)
+        while time.perf_counter() - t0 < cell.seconds:
+            due.append(st.one(first + n)[0])
             n += 1
-            failed += not np.isfinite(loss)
-            t1 = time.perf_counter()
-            if t1 - t0 >= cell.seconds:
-                break
+            if len(due) > st.ahead:
+                failed += read(due.popleft())
+        while due:
+            failed += read(due.popleft())
+        t1 = time.perf_counter()
     if cell.trace_dir:
         jax.profiler.stop_trace()
     cell.compiles.active = False
     window = t1 - t0
-    print(f"window: {n} steps in {window!r} s; compiles in window: "
+    print(f"window: {n} steps in {window!r} s, losses read up to "
+          f"{st.ahead} steps late; compiles in window: "
           f"{cell.compiles.count}", file=sys.stderr)
     mem = cell.memory_peak()
     st.drop()

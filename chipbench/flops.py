@@ -4,32 +4,81 @@
 causal attention counts its lower triangle, a frozen weight's gradient is
 not needed, and a decode step reads each live slot's context once. So a
 share of a peak built on these counts cannot pass 100 % unless the time
-leaves out part of the work. ``tests/test_flops.py`` holds the counts
-against XLA's own ``cost_analysis`` of the program's step.
+leaves out part of the work. ``tests/test_flops.py`` and
+``tests/test_families.py`` hold the counts against XLA's own
+``cost_analysis`` of the program's steps, and the adapters against the
+program's LoRA trees.
 """
 from __future__ import annotations
+
+
+DENSE = ("attn", "dense")
+# the projections that the harness's LoRA adapts: the program's default
+# ``PeftSpec.targets``; ``router`` is opt-in there
+LORA_TARGETS = ("attn", "mlp", "ssm")
 
 
 def _hd(cfg) -> int:
     return cfg.get("head_dim") or cfg["d_model"] // cfg["num_heads"]
 
 
-def layer_mats(cfg) -> dict:
-    """(d_in, d_out) of each projection of one layer."""
-    d, hd = cfg["d_model"], _hd(cfg)
-    mats = {"wq": (d, cfg["num_heads"] * hd),
-            "wk": (d, cfg["num_kv_heads"] * hd),
-            "wv": (d, cfg["num_kv_heads"] * hd),
-            "wo": (cfg["num_heads"] * hd, d),
-            "up": (d, cfg["d_ff"]), "down": (cfg["d_ff"], d)}
-    if cfg["mlp_act"] == "swiglu":
-        mats["gate"] = (d, cfg["d_ff"])
-    return mats
+def _ssm(cfg):
+    """(d_inner, heads, head_dim, groups, state) of the Mamba-2 mixer."""
+    s = cfg["ssm"]
+    d_inner = s["expand"] * cfg["d_model"]
+    return (d_inner, d_inner // s["head_dim"], s["head_dim"], s["n_groups"],
+            s["state_dim"])
+
+
+def layer_modules(cfg, kind=DENSE) -> dict:
+    """{module: {weight: (d_in, d_out)}} of the matmul weights that every
+    token of one layer of ``kind`` runs through, keyed by the LoRA target
+    that covers them (``attn``, ``ssm``, ``mlp``, ``router``; ``shared``,
+    the MoE's shared experts, is never one). The routed experts are
+    counted apart, ``top_k`` a token."""
+    mixer, ffn = kind
+    d = cfg["d_model"]
+    out = {}
+    if mixer == "attn":
+        hd = _hd(cfg)
+        out["attn"] = {"wq": (d, cfg["num_heads"] * hd),
+                       "wk": (d, cfg["num_kv_heads"] * hd),
+                       "wv": (d, cfg["num_kv_heads"] * hd),
+                       "wo": (cfg["num_heads"] * hd, d)}
+    else:
+        d_inner, heads, _, groups, state = _ssm(cfg)
+        out["ssm"] = {"in_proj": (d, 2 * d_inner + 2 * groups * state + heads),
+                      "out_proj": (d_inner, d)}
+    if ffn == "dense":
+        f = cfg["d_ff"]
+        out["mlp"] = {"up": (d, f), "down": (f, d)}
+        if cfg["mlp_act"] == "swiglu":
+            out["mlp"]["gate"] = (d, f)
+    elif ffn == "moe":
+        m = cfg["moe"]
+        out["router"] = {"router": (d, m["num_experts"])}
+        if m.get("num_shared_experts"):
+            f = m["d_ff_expert"] * m["num_shared_experts"]
+            out["shared"] = {"shared_gate": (d, f), "shared_up": (d, f),
+                             "shared_down": (f, d)}
+    return out
+
+
+def layer_mats(cfg, kind=DENSE) -> dict:
+    """(d_in, d_out) of each of those weights of one layer."""
+    return {k: v for mats in layer_modules(cfg, kind).values()
+            for k, v in mats.items()}
 
 
 def layer_params(cfg) -> int:
-    """Matmul weights of one layer."""
+    """Matmul weights of one dense layer."""
     return sum(a * b for a, b in layer_mats(cfg).values())
+
+
+def layer_lora(cfg, kind, rank: int) -> int:
+    """Adapter weights (``A`` and ``B``) of one layer of ``kind``."""
+    return sum(rank * (a + b) for t, m in layer_modules(cfg, kind).items()
+               if t in LORA_TARGETS for a, b in m.values())
 
 
 def head_params(cfg) -> int:
@@ -37,7 +86,8 @@ def head_params(cfg) -> int:
 
 
 def weight_bytes(cfg, itemsize: int = 4) -> int:
-    """Every weight a full forward reads, embedding rows aside."""
+    """Every weight a full forward of a dense model reads, embedding rows
+    aside."""
     d, L = cfg["d_model"], cfg["num_layers"]
     bias = 0
     if cfg.get("attn_bias"):
@@ -53,25 +103,67 @@ def causal_attn_flops(cfg, seq: int) -> int:
     return 2 * seq * seq * cfg["num_heads"] * _hd(cfg)
 
 
-def train_step_flops(cfg, *, cohort: int, batch: int, seq: int,
+def ssd_flops(cfg, seq: int) -> int:
+    """The chunked SSD of one causal sequence, one Mamba-2 layer, forward
+    (arXiv:2405.21060 §6): within each chunk of ``Q`` positions the lower
+    triangle of ``C B^T`` (per group) and its product with the values (per
+    head), each chunk's final state ``B^T X`` and the states' outputs
+    ``C h`` (per head), the recurrence over chunk states; a tail shorter
+    than a chunk runs the recurrence a position at a time."""
+    _, heads, p, groups, n = _ssm(cfg)
+    q = cfg["ssm"]["chunk_size"]
+    c, tail = divmod(seq, q)
+    return (c * q * q * (groups * n + heads * p)
+            + 4 * c * q * heads * n * p + 2 * c * heads * p * n
+            + 4 * tail * heads * n * p)
+
+
+def train_step_flops(cfg, *, kinds, cohort: int, batch: int, seq: int,
                      rank: int) -> int:
-    """A split LoRA step over ``cohort * batch`` sequences of ``seq``.
+    """A split LoRA step over ``cohort * batch`` sequences of ``seq``,
+    counted layer by layer by its kind: ``kinds`` holds the (mixer, ffn)
+    of each layer as the program's ``ModelConfig`` decides it
+    (``transformer.layer_specs``).
 
     Forward through every matmul and the head (2 per weight per token);
     backward needs activation gradients through every layer above the
-    embedding (2 per weight per token), except the input gradient of the
-    first layer's q/k/v projections (nothing below them trains); the
-    adapters need their forward, input and weight gradients (6 per adapter
-    weight per token); causal attention forward once and backward twice."""
-    T = cohort * batch * seq
-    L = cfg["num_layers"]
-    mats = layer_mats(cfg)
-    P = L * layer_params(cfg) + head_params(cfg)
-    first_in = sum(a * b for k, (a, b) in mats.items()
-                   if k in ("wq", "wk", "wv"))
-    lora = L * sum(rank * (a + b) for a, b in mats.values())
-    attn = 3 * L * cohort * batch * causal_attn_flops(cfg, seq)
-    return 4 * P * T - 2 * first_in * T + 6 * lora * T + attn
+    embedding (2 per weight per token), except the input gradients of the
+    first layer's projections that read its input (q/k/v, or the Mamba-2
+    ``in_proj``, and a parallel block's MLP): nothing below them trains.
+    The adapters need their forward, input and weight
+    gradients (6 per adapter weight per token). Causal attention and the
+    SSD count forward once and backward twice. A MoE layer's router and
+    shared experts count as matmuls; its routed experts as ``top_k``
+    SwiGLU experts a token (tokens a full expert drops are counted as
+    served)."""
+    T, rows = cohort * batch * seq, cohort * batch
+    total = 4 * head_params(cfg) * T
+    for i, kind in enumerate(kinds):
+        mats = layer_mats(cfg, kind)
+        total += 4 * T * sum(a * b for a, b in mats.values())
+        total += 6 * T * layer_lora(cfg, kind, rank)
+        if i == 0:
+            first = {"wq", "wk", "wv", "in_proj"}
+            if cfg.get("parallel_block") and kind == DENSE:
+                first |= {"gate", "up"}
+            total -= 2 * T * sum(a * b for k, (a, b) in mats.items()
+                                 if k in first)
+        if kind[0] == "attn":
+            total += 3 * rows * causal_attn_flops(cfg, seq)
+        else:
+            total += 3 * rows * ssd_flops(cfg, seq)
+        if kind[1] == "moe":
+            m = cfg["moe"]
+            total += 4 * T * m["top_k"] * 3 * cfg["d_model"] \
+                * m["d_ff_expert"]
+    return total
+
+
+def _all_dense(cfg):
+    """The serving counts below know attention and dense MLP layers only:
+    a configuration with an SSM or a MoE has other layers."""
+    if cfg.get("ssm") or cfg.get("moe"):
+        raise ValueError("serving counts are for dense attention layers")
 
 
 def kv_bytes_per_token(cfg, itemsize: int = 4) -> int:
@@ -82,6 +174,7 @@ def kv_bytes_per_token(cfg, itemsize: int = 4) -> int:
 def decode_step(cfg, contexts, itemsize: int = 4):
     """(flops, bytes) of one decode step over live slots whose attention
     spans ``contexts`` positions each (the new token included)."""
+    _all_dense(cfg)
     B = len(contexts)
     L = cfg["num_layers"]
     P = L * layer_params(cfg) + head_params(cfg)
@@ -96,6 +189,7 @@ def decode_step(cfg, contexts, itemsize: int = 4):
 def prefill(cfg, seq: int, itemsize: int = 4):
     """(flops, bytes) of one prompt's prefill: its logits at the last
     position only, K/V written once."""
+    _all_dense(cfg)
     L = cfg["num_layers"]
     flops = 2 * L * layer_params(cfg) * seq + 2 * head_params(cfg) \
         + L * causal_attn_flops(cfg, seq)

@@ -1,6 +1,6 @@
 """bank_device_ms.train: device time per step of every program the training
 loop runs besides the jitted step: the client bank's cohort gather and
-scatter, which run eagerly, each op its own small program."""
+scatter, each one compiled program over the whole client tree."""
 from chipbench import trace
 
 STEP = "train_step"

@@ -101,8 +101,8 @@ class Spans:
     ``jax.profiler.TraceAnnotation``, so the trace can name idle gaps by
     what the host was doing; otherwise a span costs one branch."""
 
-    NAMES = ("batch prep", "cohort gather", "step", "scatter", "generator",
-             "admit/engine step", "window")
+    NAMES = ("batch prep", "cohort gather", "step", "scatter", "loss read",
+             "generator", "admit/engine step", "window")
 
     def __init__(self, on: bool):
         self.on = on

@@ -11,6 +11,17 @@ CONFIG = {
 CONFIG_GELU = dict(CONFIG, name="tiny-gelu", num_kv_heads=1, mlp_act="gelu",
                    attn_bias=True, mlp_bias=True)
 
+# Mamba-2 mixers (the published state size, 128) with one attention layer
+# in a period of 4, and a MoE FFN (4 experts, top 2, one shared expert) in
+# every layer; no reference covers it yet, so only the driver's Setup runs it
+CONFIG_HYBRID = dict(
+    CONFIG, name="tiny-hybrid-moe", arch_type="hybrid", num_layers=4,
+    hybrid_period=4, hybrid_attn_offsets=[2],
+    ssm={"state_dim": 128, "conv_dim": 4, "expand": 2, "head_dim": 64,
+         "n_groups": 1, "chunk_size": 32, "dt_min": 0.001, "dt_max": 0.1},
+    moe={"num_experts": 4, "top_k": 2, "d_ff_expert": 128,
+         "num_shared_experts": 1})
+
 TRAIN = {"driver": "train", "scheme": "sfl_ga", "peft": "lora",
          "lora_rank": 4, "lora_alpha": 8.0, "clients": 6, "cohort": 2,
          "sampler": "uniform", "batch": 1, "seq": 32, "optimizer": "sgd",

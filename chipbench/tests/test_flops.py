@@ -12,6 +12,7 @@ import pytest
 from chipbench import flops
 from chipbench.drivers.train import model_config
 from chipbench.tests import small
+from repro.models.transformer import layer_specs
 
 CFG = dict(small.CONFIG, num_layers=2, d_ff=1024, vocab_size=2048)
 
@@ -21,14 +22,13 @@ def _xla_flops(compiled):
     return (ca[0] if isinstance(ca, list) else ca)["flops"]
 
 
-def test_train_step_flops_within_xla_count():
+def xla_step_flops(cfg, K, b, S, r):
+    """XLA's count of the program's compiled split LoRA step (cut 1)."""
     from repro.configs.base import PeftSpec, TrainConfig
     from repro.core import algorithms as alg
     from repro.models import lm
     from repro.optim import make_optimizer
 
-    K, b, S, r = 2, 1, 128, 4
-    cfg = model_config(CFG)
     plan = lm.build_plan(cfg, 1, peft=PeftSpec("lora", r, 8.0))
     tcfg = TrainConfig(model=cfg, algo="sfl_ga", cut_layer=1,
                        compute_dtype="float32", param_dtype="float32",
@@ -43,9 +43,16 @@ def test_train_step_flops_within_xla_count():
     bt = {"tokens": i32, "labels": i32,
           "seed": jax.ShapeDtypeStruct((), jnp.uint32),
           "rho": jax.ShapeDtypeStruct((K,), jnp.float32)}
-    xla = _xla_flops(jax.jit(alg.make_train_step(plan, tcfg, opt, K))
-                     .lower(p, o, bt).compile())
-    need = flops.train_step_flops(CFG, cohort=K, batch=b, seq=S, rank=r)
+    return _xla_flops(jax.jit(alg.make_train_step(plan, tcfg, opt, K))
+                      .lower(p, o, bt).compile())
+
+
+def test_train_step_flops_within_xla_count():
+    K, b, S, r = 2, 1, 128, 4
+    cfg = model_config(CFG)
+    xla = xla_step_flops(cfg, K, b, S, r)
+    need = flops.train_step_flops(CFG, kinds=layer_specs(cfg), cohort=K,
+                                  batch=b, seq=S, rank=r)
     assert 0.85 * xla <= need <= xla
 
 

@@ -77,3 +77,33 @@ def test_lengths_follow_the_law():
     y = generate.lengths({"law": "lognormal", "median": 64, "sigma": 0.8,
                           "min": 8, "max": 512}, 2001, 1, "output")
     assert y.min() >= 8 and y.max() <= 512 and np.median(y) == 64
+
+
+@pytest.mark.parametrize("ahead_s", [0.0, 1e9])
+def test_window_counts_every_step_it_dispatched(monkeypatch, ahead_s):
+    """The training window reads the loss of every step it dispatched, the
+    ones still in flight when its time is up too: with every window loss
+    made NaN, each dispatched step is attempted and failed, whether the
+    losses are read one step late or all at the end."""
+    import jax.numpy as jnp
+
+    from chipbench.tools import calibrate
+
+    train = run.load_module("drivers", "train")
+    monkeypatch.setattr(train, "AHEAD_S", ahead_s)
+    real, calls = train.Setup.one, []
+
+    def one(self, i):
+        loss, fed = real(self, i)
+        if i < self.cell.traffic["check_steps"]:
+            return loss, fed
+        calls.append(i)
+        return jnp.float32(np.nan), fed
+
+    monkeypatch.setattr(train.Setup, "one", one)
+    cell = calibrate.cell_for("train-granite8b-k4", BIG_SEED, 0.5)
+    cell.config, cell.traffic = small.CONFIG, small.TRAIN
+    out = train.run(cell)
+    assert len(calls) > 0
+    assert out["attempted"] == out["failed"] == len(calls)
+    assert out["counts"]["steps"] == len(calls)
