@@ -24,6 +24,7 @@ _ARCH_MODULES = {
     "jamba-v0.1-52b": "jamba_v01_52b",
     "granite-20b": "granite_20b",
     "kimi-k2-1t-a32b": "kimi_k2_1t_a32b",
+    "granite-4.0-h-micro": "granite_4_0_h_micro",
 }
 
 ARCH_IDS = tuple(_ARCH_MODULES)

@@ -78,6 +78,16 @@ class ModelConfig:
     tie_embeddings: bool = False
     norm_eps: float = 1e-5
     logit_softcap: float = 0.0
+    # Granite 4.0-H (granitemoehybrid config.json names): "nope" drops the
+    # rotary embedding; x = embed(tokens) * embedding_multiplier, each
+    # sublayer's output joins the residual times residual_multiplier,
+    # attention scores are q.k * attention_multiplier (None: 1/sqrt(hd)),
+    # and logits = head(x) / logits_scaling. The defaults change nothing.
+    position_embedding_type: str = "rope"  # rope | nope
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
     # distribution hints, set by the launcher (not by architecture configs):
     # mesh axis to shard the MoE dispatch/expert-compute activations over
     # (expert parallelism), and the number of independent routing groups

@@ -47,7 +47,6 @@ from repro.compress.codecs import get_codec
 from repro.models import lm
 from repro.models import paging
 from repro.models import transformer as tf
-from repro.models.blocks import embed
 from repro.sysmodel import traffic
 from repro.sysmodel.comm import CommParams, path_loss_gain
 from repro.sysmodel.latency import token_comm_latency
@@ -184,7 +183,7 @@ class ServeEngine:
 
         def step(params, caches, tokens, live, key, codec_seed):
             # client half: embed + layers[:cut]
-            x = embed(params["embed"], tokens[:, None], self.dtype)
+            x = lm.embed_tokens(params, cfg, tokens[:, None], self.dtype)
             x, cc = tf.apply_groups_decode(params["client"], cfg,
                                            plan.client_groups, x,
                                            caches[:ncg], impl)
@@ -217,7 +216,7 @@ class ServeEngine:
             # tokens (1, S) — exact length, no padding (an SSM layer's
             # state would absorb right-padding garbage)
             S = tokens.shape[1]
-            x = embed(params["embed"], tokens, self.dtype)
+            x = lm.embed_tokens(params, cfg, tokens, self.dtype)
             positions = lm._positions(cfg, 1, S)
             x, cc = tf.apply_groups_prefill(params["client"], cfg,
                                             plan.client_groups, x,

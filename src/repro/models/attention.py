@@ -1,4 +1,5 @@
-"""GQA attention with RoPE/M-RoPE, sliding windows, and KV caches.
+"""GQA attention with RoPE/M-RoPE (or none: NoPE), sliding windows, and
+KV caches.
 
 Three entry points share one parameter tree:
 
@@ -12,6 +13,8 @@ The inner product/softmax can be swapped for the Pallas flash kernel via
 """
 from __future__ import annotations
 
+import contextlib
+import math
 from dataclasses import dataclass
 from typing import NamedTuple, Optional
 
@@ -46,21 +49,32 @@ def init_attention(key, cfg: ModelConfig, dtype=jnp.float32):
 
 
 def _project_qkv(params, cfg: ModelConfig, x, positions, cross_kv_x=None):
-    B = x.shape[0]
-    hd = cfg.resolved_head_dim
-    q = linear(params["wq"], x).reshape(B, x.shape[1], cfg.num_heads, hd)
-    src = cross_kv_x if cross_kv_x is not None else x
-    k = linear(params["wk"], src).reshape(B, src.shape[1], cfg.num_kv_heads, hd)
-    v = linear(params["wv"], src).reshape(B, src.shape[1], cfg.num_kv_heads, hd)
-    if cfg.qk_norm:
-        q = rmsnorm(params["q_norm"], q, cfg.norm_eps)
-        k = rmsnorm(params["k_norm"], k, cfg.norm_eps)
-    if positions is not None:  # rope (not for whisper/cross attention)
-        sin, cos = rope_sin_cos(positions, hd, cfg.rope_theta, cfg.mrope_sections)
-        q = apply_rope(q, sin, cos)
-        if cross_kv_x is None:
-            k = apply_rope(k, sin, cos)
-    return q, k, v
+    """q, k, v of every attention path (train, prefill, decode, paged).
+
+    Every path divides the scores by sqrt(head_dim) (``_sdpa`` and the
+    flash and paged kernels alike), so a configured ``attention_multiplier``
+    is folded into q here: q * (multiplier * sqrt(hd)) gives scores
+    q.k * multiplier on each of them. A NoPE layer's projections run
+    under the scope ``attn/nope``, with no rotary embedding."""
+    nope = cfg.position_embedding_type == "nope"
+    with jax.named_scope("attn/nope") if nope else contextlib.nullcontext():
+        B = x.shape[0]
+        hd = cfg.resolved_head_dim
+        q = linear(params["wq"], x).reshape(B, x.shape[1], cfg.num_heads, hd)
+        src = cross_kv_x if cross_kv_x is not None else x
+        k = linear(params["wk"], src).reshape(B, src.shape[1], cfg.num_kv_heads, hd)
+        v = linear(params["wv"], src).reshape(B, src.shape[1], cfg.num_kv_heads, hd)
+        if cfg.qk_norm:
+            q = rmsnorm(params["q_norm"], q, cfg.norm_eps)
+            k = rmsnorm(params["k_norm"], k, cfg.norm_eps)
+        if positions is not None and not nope:  # rope (not for whisper/cross attention)
+            sin, cos = rope_sin_cos(positions, hd, cfg.rope_theta, cfg.mrope_sections)
+            q = apply_rope(q, sin, cos)
+            if cross_kv_x is None:
+                k = apply_rope(k, sin, cos)
+        if cfg.attention_multiplier is not None:
+            q = q * (cfg.attention_multiplier * math.sqrt(hd))
+        return q, k, v
 
 
 def _sdpa(q, k, v, mask, impl: str = "jnp", logit_softcap: float = 0.0):

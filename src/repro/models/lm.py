@@ -106,17 +106,31 @@ def _positions(cfg: ModelConfig, B: int, S: int, offset: int = 0):
     return pos
 
 
+def embed_tokens(params, cfg: ModelConfig, tokens, dtype=jnp.bfloat16):
+    """The token lookup, times the configuration's embedding multiplier."""
+    x = embed(params["embed"], tokens, dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
+    return x
+
+
 def _inputs(params, cfg, tokens=None, inputs_embeds=None, dtype=jnp.bfloat16):
     if inputs_embeds is not None:
         return inputs_embeds
-    return embed(params["embed"], tokens, dtype)
+    return embed_tokens(params, cfg, tokens, dtype)
 
 
 def logits_from_hidden(params, cfg: ModelConfig, x):
+    """Final norm and head (or the tied table), divided by the
+    configuration's logits scaling."""
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     if "head" in params:
-        return linear(params["head"], x)
-    return unembed(params["embed"], x)
+        logits = linear(params["head"], x)
+    else:
+        logits = unembed(params["embed"], x)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
+    return logits
 
 
 # ---------------------------------------------------------------------------
@@ -204,7 +218,7 @@ def decode_step(params, plan: ModelPlan, token, caches, impl="jnp",
     """token: (B, 1) int32 (or (B,1,d) embeds). One step; returns (logits, caches)."""
     cfg = plan.cfg
     if token.ndim == 2:
-        x = embed(params["embed"], token, dtype)
+        x = embed_tokens(params, cfg, token, dtype)
     else:
         x = token
     x, caches = tf.apply_groups_decode(all_group_params(params), cfg,

@@ -20,6 +20,12 @@ from repro.configs.base import ModelConfig
 from repro.models.blocks import init_linear, init_rmsnorm, linear, rmsnorm
 
 
+# The SSD and the conv compute in float32 whatever the model's dtype. At
+# the chip's default precision a float32 matmul rounds its operands to
+# bfloat16, the decays exp(segsum) and the chunk states among them.
+F32 = jax.lax.Precision.HIGHEST
+
+
 class SSMCache(NamedTuple):
     conv: jnp.ndarray  # (B, k-1, conv_channels) last raw inputs
     state: jnp.ndarray  # (B, H, P, N)
@@ -61,23 +67,27 @@ def ssd_chunked(x, dt, A, B, C, chunk: int,
 
     # 1) intra-chunk (dual quadratic form)
     L = jnp.exp(segsum(Ac))  # (b,h,c,q,q)
-    y_diag = jnp.einsum("bclhn,bcshn,bhcls,bcshp->bclhp", Cc, Bc, L, xc)
+    y_diag = jnp.einsum("bclhn,bcshn,bhcls,bcshp->bclhp", Cc, Bc, L, xc,
+                        precision=F32)
 
     # 2) chunk-final states
     decay_states = jnp.exp(A_cs[..., -1:] - A_cs)  # (b,h,c,q)
-    states = jnp.einsum("bclhn,bhcl,bclhp->bchpn", Bc, decay_states, xc)
+    states = jnp.einsum("bclhn,bhcl,bclhp->bchpn", Bc, decay_states, xc,
+                        precision=F32)
 
     # 3) inter-chunk recurrence via the chunk-level decay matrix
     if initial_state is None:
         initial_state = jnp.zeros((b, h, p, n), jnp.float32)
     states = jnp.concatenate([initial_state[:, None].astype(jnp.float32), states], 1)
     chunk_decay = jnp.exp(segsum(jnp.pad(A_cs[..., -1], ((0, 0), (0, 0), (1, 0)))))
-    new_states = jnp.einsum("bhzc,bchpn->bzhpn", chunk_decay, states)
+    new_states = jnp.einsum("bhzc,bchpn->bzhpn", chunk_decay, states,
+                            precision=F32)
     prev_states, final_state = new_states[:, :-1], new_states[:, -1]
 
     # 4) inter-chunk contribution to outputs
     state_decay_out = jnp.exp(A_cs)  # (b,h,c,q)
-    y_off = jnp.einsum("bclhn,bchpn,bhcl->bclhp", Cc, prev_states, state_decay_out)
+    y_off = jnp.einsum("bclhn,bchpn,bhcl->bclhp", Cc, prev_states,
+                       state_decay_out, precision=F32)
 
     y = (y_diag + y_off).reshape(b, s, h, p)
     return y.astype(x.dtype), final_state
@@ -91,9 +101,10 @@ def ssd_step(state, x_t, dt_t, A, B_t, C_t) -> Tuple[jnp.ndarray, jnp.ndarray]:
     Cf = jnp.repeat(C_t.astype(jnp.float32), rep, axis=1)
     dtf = dt_t.astype(jnp.float32)
     dA = jnp.exp(dtf * A.astype(jnp.float32))  # (b,h)
-    upd = jnp.einsum("bhp,bhn->bhpn", x_t.astype(jnp.float32) * dtf[..., None], Bf)
+    upd = jnp.einsum("bhp,bhn->bhpn", x_t.astype(jnp.float32) * dtf[..., None], Bf,
+                     precision=F32)
     new_state = state * dA[..., None, None] + upd
-    y = jnp.einsum("bhpn,bhn->bhp", new_state, Cf)
+    y = jnp.einsum("bhpn,bhn->bhp", new_state, Cf, precision=F32)
     return y.astype(x_t.dtype), new_state
 
 
@@ -149,6 +160,7 @@ def _causal_conv(w, b, x):
         padding="VALID",
         dimension_numbers=("NWC", "WIO", "NWC"),
         feature_group_count=x.shape[-1],
+        precision=F32,
     )
     return (out + b.astype(jnp.float32)).astype(x.dtype)
 
@@ -168,7 +180,7 @@ def _ssm_inner(cfg, params, xBC_conv, dt_raw, use_kernel: bool, prev_state=None)
     y, final_state = _ssd_any_length(x, dt, A, Bmat, Cmat, s.chunk_size,
                                      prev_state, use_kernel)
     y = y + params["D"].astype(y.dtype)[None, None, :, None] * x
-    return y.reshape(B_sz, S, d_inner), final_state, (x, dt, A, Bmat, Cmat)
+    return y.reshape(B_sz, S, d_inner), final_state
 
 
 def _ssd_tail_sequential(x, dt, A, B, C, state):
@@ -219,14 +231,27 @@ def _ssd_any_length(x, dt, A, B, C, chunk: int, prev_state, use_kernel: bool):
     return y_tail.astype(x.dtype), state
 
 
+def _mixer(params, cfg: ModelConfig, x, use_kernel: bool):
+    """The Mamba-2 mixer over a whole sequence, under the named scopes
+    ``ssm/in_proj``, ``ssm/conv``, ``ssm/ssd``, ``ssm/gate_norm`` and
+    ``ssm/out_proj``. Returns (out, the conv's raw input, final state)."""
+    with jax.named_scope("ssm/in_proj"):
+        zxbcdt = linear(params["in_proj"], x)
+    z, xBC_raw, dt_raw = _split_in_proj(cfg, zxbcdt)
+    with jax.named_scope("ssm/conv"):
+        xBC = jax.nn.silu(_causal_conv(params["conv_w"], params["conv_b"],
+                                       xBC_raw))
+    with jax.named_scope("ssm/ssd"):
+        y, final_state = _ssm_inner(cfg, params, xBC, dt_raw, use_kernel)
+    with jax.named_scope("ssm/gate_norm"):
+        y = rmsnorm(params["norm"], y * jax.nn.silu(z), cfg.norm_eps)
+    with jax.named_scope("ssm/out_proj"):
+        return linear(params["out_proj"], y), xBC_raw, final_state
+
+
 def mamba2_train(params, cfg: ModelConfig, x, use_kernel: bool = False):
     """x: (B, S, d_model) -> (B, S, d_model)."""
-    zxbcdt = linear(params["in_proj"], x)
-    z, xBC, dt_raw = _split_in_proj(cfg, zxbcdt)
-    xBC = jax.nn.silu(_causal_conv(params["conv_w"], params["conv_b"], xBC))
-    y, _, _ = _ssm_inner(cfg, params, xBC, dt_raw, use_kernel)
-    y = rmsnorm(params["norm"], y * jax.nn.silu(z), cfg.norm_eps)
-    return linear(params["out_proj"], y)
+    return _mixer(params, cfg, x, use_kernel)[0]
 
 
 def init_ssm_cache(cfg: ModelConfig, batch: int, dtype) -> SSMCache:
@@ -241,44 +266,48 @@ def init_ssm_cache(cfg: ModelConfig, batch: int, dtype) -> SSMCache:
 
 def mamba2_prefill(params, cfg: ModelConfig, x, use_kernel: bool = False):
     s = cfg.ssm
-    zxbcdt = linear(params["in_proj"], x)
-    z, xBC_raw, dt_raw = _split_in_proj(cfg, zxbcdt)
-    xBC = jax.nn.silu(_causal_conv(params["conv_w"], params["conv_b"], xBC_raw))
-    y, final_state, _ = _ssm_inner(cfg, params, xBC, dt_raw, use_kernel)
-    y = rmsnorm(params["norm"], y * jax.nn.silu(z), cfg.norm_eps)
+    out, xBC_raw, final_state = _mixer(params, cfg, x, use_kernel)
     cache = SSMCache(
         conv=xBC_raw[:, -(s.conv_dim - 1):, :],
         state=final_state,
         length=jnp.asarray(x.shape[1], jnp.int32),
     )
-    return linear(params["out_proj"], y), cache
+    return out, cache
 
 
 def mamba2_decode(params, cfg: ModelConfig, x, cache: SSMCache):
-    """x: (B, 1, d_model). One recurrent step."""
+    """x: (B, 1, d_model). One recurrent step, under ``_mixer``'s scopes."""
     s = cfg.ssm
     d_inner, H, conv_ch = _dims(cfg)
     gn = s.n_groups * s.state_dim
-    zxbcdt = linear(params["in_proj"], x[:, 0, :])  # (B, ...)
+    with jax.named_scope("ssm/in_proj"):
+        zxbcdt = linear(params["in_proj"], x[:, 0, :])  # (B, ...)
     z, xBC_t, dt_raw = _split_in_proj(cfg, zxbcdt)
     # conv step over the last conv_dim inputs
     hist = jnp.concatenate([cache.conv, xBC_t[:, None, :]], axis=1)  # (B,k,CH)
-    w = params["conv_w"].astype(jnp.float32)  # (k, CH)
-    xBC = jnp.einsum("bkc,kc->bc", hist.astype(jnp.float32), w)
-    xBC = jax.nn.silu(xBC + params["conv_b"].astype(jnp.float32)).astype(x.dtype)
+    with jax.named_scope("ssm/conv"):
+        w = params["conv_w"].astype(jnp.float32)  # (k, CH)
+        xBC = jnp.einsum("bkc,kc->bc", hist.astype(jnp.float32), w,
+                         precision=F32)
+        xBC = jax.nn.silu(xBC + params["conv_b"].astype(jnp.float32)
+                          ).astype(x.dtype)
     xs, Bm, Cm = jnp.split(xBC, [d_inner, d_inner + gn], axis=-1)
     x_t = xs.reshape(-1, H, s.head_dim)
     B_t = Bm.reshape(-1, s.n_groups, s.state_dim)
     C_t = Cm.reshape(-1, s.n_groups, s.state_dim)
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + params["dt_bias"])
-    A = -jnp.exp(params["A_log"])
-    y_t, new_state = ssd_step(cache.state, x_t, dt, A, B_t, C_t)
-    y_t = y_t + params["D"].astype(y_t.dtype)[None, :, None] * x_t
+    with jax.named_scope("ssm/ssd"):
+        dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + params["dt_bias"])
+        A = -jnp.exp(params["A_log"])
+        y_t, new_state = ssd_step(cache.state, x_t, dt, A, B_t, C_t)
+        y_t = y_t + params["D"].astype(y_t.dtype)[None, :, None] * x_t
     y = y_t.reshape(-1, 1, d_inner)
-    y = rmsnorm(params["norm"], y * jax.nn.silu(z[:, None, :]), cfg.norm_eps)
+    with jax.named_scope("ssm/gate_norm"):
+        y = rmsnorm(params["norm"], y * jax.nn.silu(z[:, None, :]),
+                    cfg.norm_eps)
     new_cache = SSMCache(conv=hist[:, 1:, :], state=new_state,
                          length=cache.length + 1)
-    return linear(params["out_proj"], y), new_cache
+    with jax.named_scope("ssm/out_proj"):
+        return linear(params["out_proj"], y), new_cache
 
 
 def ssm_flops_per_token(cfg: ModelConfig) -> int:

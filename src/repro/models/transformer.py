@@ -226,6 +226,13 @@ def merge_group_loras(params_list, lora_list):
 # Forward passes
 # ---------------------------------------------------------------------------
 
+def _res(cfg, y):
+    """A sublayer's output as it joins the residual stream."""
+    if cfg.residual_multiplier == 1.0:
+        return y
+    return y * cfg.residual_multiplier
+
+
 def _sublayer_train(cfg, spec, p, x, positions, impl):
     mixer, ffn = spec
     aux = jnp.zeros((), jnp.float32)
@@ -233,16 +240,19 @@ def _sublayer_train(cfg, spec, p, x, positions, impl):
     if cfg.parallel_block and mixer == "attn" and ffn == "dense":
         a = attn_mod.attend_train(p["attn"], cfg, h, positions, impl)
         m = mlp(p["mlp"], h, cfg.mlp_act)
-        return x + a + m, aux
+        return x + _res(cfg, a) + _res(cfg, m), aux
     if mixer == "attn":
-        x = x + attn_mod.attend_train(p["attn"], cfg, h, positions, impl)
+        x = x + _res(cfg, attn_mod.attend_train(p["attn"], cfg, h, positions,
+                                                impl))
     else:
-        x = x + ssm_mod.mamba2_train(p["ssm"], cfg, h, use_kernel=(impl == "flash"))
+        x = x + _res(cfg, ssm_mod.mamba2_train(p["ssm"], cfg, h,
+                                               use_kernel=(impl == "flash")))
     if ffn == "dense":
-        x = x + mlp(p["mlp"], rmsnorm(p["norm2"], x, cfg.norm_eps), cfg.mlp_act)
+        x = x + _res(cfg, mlp(p["mlp"], rmsnorm(p["norm2"], x, cfg.norm_eps),
+                              cfg.mlp_act))
     elif ffn == "moe":
         y, aux = moe_mod.moe_apply(p["moe"], cfg, rmsnorm(p["norm2"], x, cfg.norm_eps))
-        x = x + y
+        x = x + _res(cfg, y)
     return x, aux
 
 
@@ -271,18 +281,19 @@ def _sublayer_prefill(cfg, spec, p, x, positions, max_len, impl):
     if cfg.parallel_block and mixer == "attn" and ffn == "dense":
         a, cache = attn_mod.attend_prefill(p["attn"], cfg, h, positions, max_len, impl)
         m = mlp(p["mlp"], h, cfg.mlp_act)
-        return x + a + m, cache
+        return x + _res(cfg, a) + _res(cfg, m), cache
     if mixer == "attn":
         out, cache = attn_mod.attend_prefill(p["attn"], cfg, h, positions, max_len, impl)
-        x = x + out
+        x = x + _res(cfg, out)
     else:
         out, cache = ssm_mod.mamba2_prefill(p["ssm"], cfg, h, use_kernel=(impl == "flash"))
-        x = x + out
+        x = x + _res(cfg, out)
     if ffn == "dense":
-        x = x + mlp(p["mlp"], rmsnorm(p["norm2"], x, cfg.norm_eps), cfg.mlp_act)
+        x = x + _res(cfg, mlp(p["mlp"], rmsnorm(p["norm2"], x, cfg.norm_eps),
+                              cfg.mlp_act))
     elif ffn == "moe":
         y, _ = moe_mod.moe_apply(p["moe"], cfg, rmsnorm(p["norm2"], x, cfg.norm_eps))
-        x = x + y
+        x = x + _res(cfg, y)
     return x, cache
 
 
@@ -322,18 +333,19 @@ def _sublayer_decode(cfg, spec, p, x, cache, impl):
     if cfg.parallel_block and mixer == "attn" and ffn == "dense":
         a, cache = _attend_decode_any(p["attn"], cfg, h, cache, impl)
         m = mlp(p["mlp"], h, cfg.mlp_act)
-        return x + a + m, cache
+        return x + _res(cfg, a) + _res(cfg, m), cache
     if mixer == "attn":
         out, cache = _attend_decode_any(p["attn"], cfg, h, cache, impl)
-        x = x + out
+        x = x + _res(cfg, out)
     else:
         out, cache = ssm_mod.mamba2_decode(p["ssm"], cfg, h, cache)
-        x = x + out
+        x = x + _res(cfg, out)
     if ffn == "dense":
-        x = x + mlp(p["mlp"], rmsnorm(p["norm2"], x, cfg.norm_eps), cfg.mlp_act)
+        x = x + _res(cfg, mlp(p["mlp"], rmsnorm(p["norm2"], x, cfg.norm_eps),
+                              cfg.mlp_act))
     elif ffn == "moe":
         y, _ = moe_mod.moe_apply(p["moe"], cfg, rmsnorm(p["norm2"], x, cfg.norm_eps))
-        x = x + y
+        x = x + _res(cfg, y)
     return x, cache
 
 

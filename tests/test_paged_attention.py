@@ -213,8 +213,20 @@ def _roll_decode(params, cfg, x, prefill_len, max_len):
     return jnp.concatenate(ys, axis=1), cache
 
 
+def _granite_cfg():
+    """granite-4.0-h-micro's attention, reduced: NoPE, scores x 1/64."""
+    return reduced_config(get_config("granite-4.0-h-micro"))
+
+
 def test_decode_matches_prefill_every_position():
-    cfg = _cfg()
+    _decode_matches_prefill(_cfg())
+
+
+def test_decode_matches_prefill_nope_scaled():
+    _decode_matches_prefill(_granite_cfg())
+
+
+def _decode_matches_prefill(cfg):
     S = 24
     params = attn_mod.init_attention(jax.random.key(1), cfg)
     x = jax.random.normal(jax.random.key(2), (2, S, cfg.d_model))
@@ -259,7 +271,14 @@ def test_paged_decode_matches_dense_decode():
     """Same prompt, same decode steps: the paged cache stores the exact
     bits the dense cache does, and the paged attend stays within fp32
     tolerance of the dense attend (different softmax op order)."""
-    cfg = _cfg()
+    _paged_matches_dense(_cfg())
+
+
+def test_paged_decode_matches_dense_decode_nope_scaled():
+    _paged_matches_dense(_granite_cfg())
+
+
+def _paged_matches_dense(cfg):
     slots, S, steps, page = 2, 11, 4, 8
     max_len = 32
     params = attn_mod.init_attention(jax.random.key(7), cfg)
