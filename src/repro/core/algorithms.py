@@ -427,7 +427,10 @@ def _make_local_step(loss_fn: Callable, opt: Optimizer,
     """One optimizer step. Full path: differentiate the whole split tree
     (byte-identical to the pre-PEFT step). PEFT path: the frozen base is
     held out as a non-differentiated argument, so grads — and the
-    optimizer state threaded through — exist only for adapter leaves."""
+    optimizer state threaded through — exist only for adapter leaves,
+    and the step returns only the trainable ``{client, server}`` tree.
+    The caller keeps the base: a jitted step that returned it unchanged
+    would copy every base leaf into a fresh output buffer each call."""
     if not peft:
         def local_step(params, opt_state, batch, seed, w):
             (loss, metrics), grads = jax.value_and_grad(
@@ -442,20 +445,55 @@ def _make_local_step(loss_fn: Callable, opt: Optimizer,
         return loss_fn(dict(tr, base=base), batch, seed, w)
 
     def local_step(params, opt_state, batch, seed, w):
-        base = params["base"]
-        tr = {k: v for k, v in params.items() if k != "base"}
+        tr = trainable_params(params)
         (loss, metrics), grads = jax.value_and_grad(
-            tr_loss, has_aux=True)(tr, base, batch, seed, w)
+            tr_loss, has_aux=True)(tr, params["base"], batch, seed, w)
         updates, opt_state = opt.update(grads, opt_state, tr)
         tr = apply_updates(tr, updates)
-        return dict(tr, base=base), opt_state, dict(metrics, loss=loss)
+        return tr, opt_state, dict(metrics, loss=loss)
 
     return local_step
+
+
+def _local_steps(local_step: Callable, engine: ProtocolEngine, tau: int,
+                 params, opt_state, batch, seed, w):
+    """τ local steps from ``params``; returns ``(trained, opt_state,
+    metrics)``. A frozen PEFT base is read once and closed over: it is
+    never in the scan's carry, nor in what comes back."""
+    if tau == 1:
+        return local_step(params, opt_state, batch, seed, w)
+    # τ local steps: tokens/labels carry a local-epoch axis
+    # (N, τ, b, S[, d]); scan over it with per-epoch codec seeds.
+    want = 5 if jnp.issubdtype(batch["tokens"].dtype, jnp.floating) else 4
+    assert batch["tokens"].ndim == want, (
+        f"tau={tau} needs a local-epoch axis: tokens (N, tau, b, S"
+        f"{', d' if want == 5 else ''}), got {batch['tokens'].shape}")
+    xs = jnp.moveaxis(batch["tokens"], 1, 0)
+    ys = jnp.moveaxis(batch["labels"], 1, 0)
+    seeds = engine.epoch_seeds(seed, xs.shape[0])
+    frozen = {k: v for k, v in params.items() if k == "base"}
+
+    def body(carry, sl):
+        p, s = carry
+        t, l, sd = sl
+        p, s, m = local_step(dict(p, **frozen), s,
+                             {"tokens": t, "labels": l}, sd, w)
+        return (p, s), m
+
+    (params, opt_state), ms = jax.lax.scan(
+        body, (trainable_params(params), opt_state), (xs, ys, seeds))
+    return params, opt_state, jax.tree.map(jnp.mean, ms)
 
 
 def make_train_step(plan: lm_mod.ModelPlan, tcfg: TrainConfig, opt: Optimizer,
                     n_clients: int, rho: Optional[jnp.ndarray] = None,
                     engine: Optional[ProtocolEngine] = None) -> Callable:
+    """``train_step(params, opt_state, batch) -> (trained, opt_state,
+    metrics)``: τ local steps, then the scheme's round-end aggregation.
+    On a full-parameter tree ``trained`` is the whole new tree; with a
+    PEFT plan it is only the trainable ``{client, server}`` adapter tree,
+    and the caller keeps the frozen ``base`` it passed in (DESIGN.md
+    §17)."""
     assert tcfg.algo in ALGOS, tcfg.algo
     rho = uniform_rho(n_clients) if rho is None else rho
     # launchers pass their own engine when they attach an obs traffic
@@ -475,29 +513,8 @@ def make_train_step(plan: lm_mod.ModelPlan, tcfg: TrainConfig, opt: Optimizer,
         # round broadcast the aggregate into the bank), so row 0
         client0 = jax.tree.map(lambda x: x[0], params["client"]) \
             if (w is not None and engine.spec.client_aggregate) else None
-        if tau == 1:
-            params, opt_state, metrics = local_step(params, opt_state,
-                                                    batch, seed, w)
-        else:
-            # τ local steps: tokens/labels carry a local-epoch axis
-            # (N, τ, b, S[, d]); scan over it with per-epoch codec seeds.
-            want = 5 if jnp.issubdtype(batch["tokens"].dtype, jnp.floating) else 4
-            assert batch["tokens"].ndim == want, (
-                f"tau={tau} needs a local-epoch axis: tokens (N, tau, b, S"
-                f"{', d' if want == 5 else ''}), got {batch['tokens'].shape}")
-            xs = jnp.moveaxis(batch["tokens"], 1, 0)
-            ys = jnp.moveaxis(batch["labels"], 1, 0)
-            seeds = engine.epoch_seeds(seed, xs.shape[0])
-
-            def body(carry, sl):
-                p, s = carry
-                t, l, sd = sl
-                p, s, m = local_step(p, s, {"tokens": t, "labels": l}, sd, w)
-                return (p, s), m
-
-            (params, opt_state), ms = jax.lax.scan(
-                body, (params, opt_state), (xs, ys, seeds))
-            metrics = jax.tree.map(jnp.mean, ms)
+        params, opt_state, metrics = _local_steps(
+            local_step, engine, tau, params, opt_state, batch, seed, w)
         if engine.spec.client_aggregate:
             # traditional SFL: aggregate client-side models every round —
             # the φ(v)-byte collective SFL-GA eliminates.
@@ -549,23 +566,8 @@ def make_gen_step(plan: lm_mod.ModelPlan, tcfg: TrainConfig, opt: Optimizer,
         seed = batch.get("seed", 0)
         w = batch.get("rho")
         server0 = params["server"]
-        if tau == 1:
-            params, opt_state, metrics = local_step(params, opt_state,
-                                                    batch, seed, w)
-        else:
-            xs = jnp.moveaxis(batch["tokens"], 1, 0)
-            ys = jnp.moveaxis(batch["labels"], 1, 0)
-            seeds = engine.epoch_seeds(seed, xs.shape[0])
-
-            def body(carry, sl):
-                p, s = carry
-                t, l, sd = sl
-                p, s, m = local_step(p, s, {"tokens": t, "labels": l}, sd, w)
-                return (p, s), m
-
-            (params, opt_state), ms = jax.lax.scan(
-                body, (params, opt_state), (xs, ys, seeds))
-            metrics = jax.tree.map(jnp.mean, ms)
+        params, opt_state, metrics = _local_steps(
+            local_step, engine, tau, params, opt_state, batch, seed, w)
         delta = jax.tree.map(
             lambda p, a: p.astype(jnp.float32) - a.astype(jnp.float32),
             params["server"], server0)

@@ -343,8 +343,10 @@ def train_lm(args) -> dict:
                  "seed": round_seed(args.seed, i)}
         with _maybe_profile(args, i), rec.span("step", cut=cut):
             if sampler is None:
-                params, opt_state, m = steps_by_cut[cut](params, opt_state,
-                                                         batch)
+                # a PEFT step returns only the trainable tree: keep the base
+                new, opt_state, m = steps_by_cut[cut](params, opt_state,
+                                                      batch)
+                params = dict(params, **new)
             else:
                 # partial participation: gather the step-i cohort (params +
                 # optimizer moments), train with unbiased cohort weights,
