@@ -40,6 +40,7 @@ import jax.numpy as jnp
 
 from repro import obs
 from repro.configs.base import ModelConfig, TrainConfig
+from repro.core.bank import broadcast_row0, gather_rows, scatter_rows
 from repro.core.gradagg import uniform_rho
 from repro.core.protocol import ProtocolEngine, aggregate_cohort
 from repro.models import lm as lm_mod
@@ -225,27 +226,6 @@ def resplit_opt_state(opt_state: Dict, old_plan: lm_mod.ModelPlan,
     return out
 
 
-# One compiled program per cohort gather / scatter (DESIGN.md §13): the
-# per-leaf expressions under one jit, with ``idx`` traced so every cohort
-# of the same size K reuses the program. No donation: callers keep the
-# old bank after a scatter.
-@jax.jit
-def _gather_rows(tree, idx):
-    return jax.tree.map(lambda x: x[idx], tree)
-
-
-@jax.jit
-def _scatter_rows(bank, cohort, idx):
-    return jax.tree.map(lambda b, u: b.at[idx].set(u), bank, cohort)
-
-
-@jax.jit
-def _broadcast_row0(bank, cohort):
-    return jax.tree.map(
-        lambda b, u: jnp.broadcast_to(u[0][None], b.shape).astype(b.dtype),
-        bank, cohort)
-
-
 def gather_cohort(tree: Dict, idx) -> Dict:
     """Slice the client bank to the round's cohort rows (DESIGN.md §13).
 
@@ -253,10 +233,11 @@ def gather_cohort(tree: Dict, idx) -> Dict:
     themselves or one optimizer moment. The server side is shared (O(1)
     in N) and passes through; client leaves lose their (N,) bank axis
     for a (K,) cohort axis, ready for the jitted train step. Host span
-    ``bank/gather``: one compiled program for the whole client tree."""
+    ``bank/gather``: one compiled program for the whole client tree
+    (``core.bank.gather_rows``)."""
     with obs.get_recorder().span("bank/gather"):
         return dict(tree,
-                    client=_gather_rows(tree["client"], jnp.asarray(idx)))
+                    client=gather_rows(tree["client"], jnp.asarray(idx)))
 
 
 def scatter_cohort(bank: Dict, cohort: Dict, idx,
@@ -268,13 +249,14 @@ def scatter_cohort(bank: Dict, cohort: Dict, idx,
     same client). ``broadcast_client=True`` writes cohort row 0 to EVERY
     bank row — the client-aggregating schemes (sfl), whose train step
     already made all cohort rows the new global client model. Host span
-    ``bank/scatter``: one compiled program for the whole client tree."""
+    ``bank/scatter``: one compiled program for the whole client tree
+    (``core.bank.scatter_rows`` or ``broadcast_row0``)."""
     with obs.get_recorder().span("bank/scatter"):
         if broadcast_client:
-            client = _broadcast_row0(bank["client"], cohort["client"])
+            client = broadcast_row0(bank["client"], cohort["client"])
         else:
-            client = _scatter_rows(bank["client"], cohort["client"],
-                                   jnp.asarray(idx))
+            client = scatter_rows(bank["client"], cohort["client"],
+                                  jnp.asarray(idx))
         return dict(bank, client=client, server=cohort["server"])
 
 

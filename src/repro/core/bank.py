@@ -12,8 +12,11 @@ three interchangeable backends:
 
 ``device``
     Today's layout: the stacked pytree lives on device, gathers and
-    scatters are device-side indexing. The default, and the bit-parity
-    baseline — every operation is the exact pre-bank expression.
+    scatters are device-side indexing through this module's compiled
+    programs (``gather_rows``, ``scatter_rows``, ``broadcast_row0``):
+    one launch for the whole tree, not one per leaf. The default, and
+    the bit-parity baseline — every operation is the exact pre-bank
+    expression.
 ``host``
     The bank lives in host (numpy) memory. A round gathers only the
     K-slice onto device and scatters it back; device memory for client
@@ -30,8 +33,9 @@ three interchangeable backends:
     The bank is one jax.Array per leaf, sharded over the client axes of
     a ``launch.mesh`` mesh (``launch.shardings.bank_sharding``) — the
     multi-host answer, finally reusing the mesh/sharding layer beyond
-    the LLM path. Gathers/scatters are cross-shard device indexing;
-    per-device client-state memory is O(N / shards).
+    the LLM path. Gathers/scatters are cross-shard device indexing
+    through the same compiled programs as ``device``; per-device
+    client-state memory is O(N / shards).
 
 The bank is structure-agnostic: it owns any pytree whose leaves carry a
 leading ``(N,)`` axis when ``stacked`` (the simulator's list of layer
@@ -88,12 +92,28 @@ def _reshape_w(w, p):
     return jnp.asarray(w).reshape((-1,) + (1,) * (p.ndim - 1))
 
 
-def make_bank(tree, *, n_clients: int, stacked: bool, backend: str = "device",
-              mesh=None, chunk_rows: int = DEFAULT_CHUNK_ROWS,
-              prefetch: bool = True) -> "ClientBank":
-    return ClientBank(tree, n_clients=n_clients, stacked=stacked,
-                      backend=backend, mesh=mesh, chunk_rows=chunk_rows,
-                      prefetch=prefetch)
+# One compiled program per cohort gather / scatter (DESIGN.md §13): the
+# per-leaf expressions under one jit, with ``idx`` traced so every cohort
+# of the same size K reuses the program (one per tree structure and K).
+# No donation: callers keep the old bank after a scatter. Every device-
+# side mover of client rows goes through these three: the ``device`` and
+# ``sharded`` backends below, ``algorithms.gather_cohort``/
+# ``scatter_cohort`` and the LM launcher's async merge.
+@jax.jit
+def gather_rows(tree, idx):
+    return jax.tree.map(lambda x: x[idx], tree)
+
+
+@jax.jit
+def scatter_rows(bank, cohort, idx):
+    return jax.tree.map(lambda b, u: b.at[idx].set(u), bank, cohort)
+
+
+@jax.jit
+def broadcast_row0(bank, cohort):
+    return jax.tree.map(
+        lambda b, u: jnp.broadcast_to(u[0][None], b.shape).astype(b.dtype),
+        bank, cohort)
 
 
 class ClientBank:
@@ -281,17 +301,11 @@ class ClientBank:
             return self._gather(idx, t)
 
     def _gather(self, idx, t):
-        if self.backend == "device":
+        if self.backend != "host":
             if idx is None:
                 return self._tree
-            jidx = jnp.asarray(idx)
-            return jax.tree.map(lambda b: b[jidx], self._tree)
-        if self.backend == "sharded":
-            if idx is None:
-                return self._tree
-            jidx = jnp.asarray(idx)
-            # cross-shard gather; the K-slice lands unsharded (replicated)
-            return jax.tree.map(lambda b: b[jidx], self._tree)
+            # sharded: a cross-shard gather; the K-slice lands replicated
+            return gather_rows(self._tree, jnp.asarray(idx))
         # host
         staged = self._staged
         if staged is not None and t is not None and staged[0] == t \
@@ -362,22 +376,17 @@ class ClientBank:
             self._scatter(idx, updated, broadcast)
 
     def _scatter(self, idx, updated, broadcast: bool) -> None:
-        if self.backend in ("device", "sharded"):
+        if self.backend != "host":
             if broadcast:
-                new = jax.tree.map(
-                    lambda b, u: jnp.broadcast_to(
-                        u[0][None], b.shape).astype(b.dtype) + 0.0,
-                    self._tree, updated)
+                new = broadcast_row0(self._tree, updated)
             elif idx is None:
                 new = updated
             else:
-                jidx = jnp.asarray(idx)
-                new = jax.tree.map(lambda b, u: b.at[jidx].set(u),
-                                   self._tree, updated)
+                new = scatter_rows(self._tree, updated, jnp.asarray(idx))
             if self.backend == "sharded" and (broadcast or idx is not None):
-                # pin the result back to the bank sharding (`.at[].set`
-                # may leave the output replicated after a cross-shard
-                # scatter); a no-op when already laid out right
+                # pin the result back to the bank sharding (the compiled
+                # scatter may leave the output replicated after a cross-
+                # shard scatter); a no-op when already laid out right
                 new = jax.tree.map(
                     lambda b, old: jax.device_put(b, old.sharding),
                     new, self._tree)

@@ -194,16 +194,11 @@ def train_lm(args) -> dict:
         params, opt_state = state["params"], state["opt"]
         obs.log(f"resumed from {args.resume} at step {done} (cut {cut0}); "
                 f"--steps {args.steps} more to run")
-    # --bank host: the O(N) client-side stacks (params + any optimizer
-    # moments) move into host-resident ClientBanks; each step gathers
-    # only the K-cohort slice onto device and the banks double-buffer
-    # the copies behind the jitted step (core.bank)
-    pbank, obanks = None, {}
-    if args.bank != "device":
-        if args.bank != "host":
-            raise SystemExit("--bank sharded is CNN-mode only; LM runs "
-                             "shard the client bank via launch.shardings "
-                             "on real meshes")
+    if args.bank == "sharded":
+        raise SystemExit("--bank sharded is CNN-mode only; LM runs "
+                         "shard the client bank via launch.shardings "
+                         "on real meshes")
+    if args.bank == "host":
         if schedule is not None and peft is None:
             raise SystemExit("--bank host cannot run --dynamic-cut with "
                              "--peft none: a full-parameter resplit would "
@@ -216,19 +211,6 @@ def train_lm(args) -> dict:
             raise SystemExit("--bank host needs --cohort in LM mode (the "
                              "identity cohort re-gathers the whole bank "
                              "every step)")
-        from repro.core.bank import ClientBank
-
-        pbank = ClientBank(params["client"], n_clients=n, stacked=True,
-                           backend="host")
-        params = dict(params, client=None)  # the bank owns the client side
-        for mk in ("m", "v", "mu"):
-            if mk in opt_state:
-                obanks[mk] = ClientBank(opt_state[mk]["client"], n_clients=n,
-                                        stacked=True, backend="host")
-                opt_state[mk] = dict(opt_state[mk], client=None)
-        off = pbank.nbytes + sum(b.nbytes for b in obanks.values())
-        obs.log(f"client bank: host backend ({off / 1e6:.2f} MB params"
-                f"{' + moments' if obanks else ''} off-device)")
     steps_by_cut = {cut0: jax.jit(alg.make_train_step(plans[cut0], tcfg, opt,
                                                       K, engine=engine))}
     if args.async_mode:
@@ -253,6 +235,25 @@ def train_lm(args) -> dict:
         return _run_lm_async(args, cfg, plans[cut0], tcfg, engine, params,
                              opt_state, steps_by_cut[cut0], gen_fn, rec,
                              n, K, b, S, tau)
+    # the O(N) client-side stacks (params + any optimizer moments) live in
+    # ClientBanks (core.bank); each step gathers only the cohort's rows.
+    # --bank host keeps them in host memory and double-buffers the copies
+    # behind the jitted step
+    from repro.core.bank import ClientBank
+
+    pbank = ClientBank(params["client"], n_clients=n, stacked=True,
+                       backend=args.bank)
+    params = dict(params, client=None)  # the bank owns the client side
+    obanks = {}
+    for mk in ("m", "v", "mu"):
+        if mk in opt_state:
+            obanks[mk] = ClientBank(opt_state[mk]["client"], n_clients=n,
+                                    stacked=True, backend=args.bank)
+            opt_state[mk] = dict(opt_state[mk], client=None)
+    if args.bank == "host":
+        off = pbank.nbytes + sum(b.nbytes for b in obanks.values())
+        obs.log(f"client bank: host backend ({off / 1e6:.2f} MB params"
+                f"{' + moments' if obanks else ''} off-device)")
 
     def per_client_numel(client_tree):
         leaves = jax.tree.leaves(client_tree)
@@ -283,33 +284,28 @@ def train_lm(args) -> dict:
                         alg.make_train_step(plans[v], tcfg, opt, K,
                                             engine=engine))
                 # the whole BANK migrates (resplit is N-agnostic); wire
-                # cost is paid by the K participants of the step
-                if pbank is None:
-                    per_old = per_client_numel(params["client"])
-                    params = alg.resplit_lm_params(params, plans[cut],
-                                                   plans[v])
-                    opt_state = alg.resplit_opt_state(opt_state, plans[cut],
-                                                      plans[v])
-                    per_new = per_client_numel(params["client"])
-                else:
-                    # host bank (LoRA-only, see the guard above): pull the
-                    # adapter rows onto device, resplit, swap the banks'
-                    # contents — any staged prefetch is invalidated by
-                    # replace(), so the next gather re-slices
-                    fp = dict(params, client=pbank.tree)
-                    fo = dict(opt_state)
-                    for mk, bk in obanks.items():
-                        fo[mk] = dict(opt_state[mk], client=bk.tree)
-                    per_old = per_client_numel(fp["client"])
-                    fp = alg.resplit_lm_params(fp, plans[cut], plans[v])
-                    fo = alg.resplit_opt_state(fo, plans[cut], plans[v])
-                    per_new = per_client_numel(fp["client"])
-                    pbank.replace(fp["client"])
-                    params = dict(fp, client=None)
-                    opt_state = fo
-                    for mk, bk in obanks.items():
-                        bk.replace(fo[mk]["client"])
-                        opt_state[mk] = dict(fo[mk], client=None)
+                # cost is paid by the K participants of the step. The
+                # banks' contents are swapped — on the host backend (LoRA-
+                # only, see the guard above) replace() also invalidates
+                # any staged prefetch, so the next gather re-slices. The
+                # trees are read after a flush, so every pending host
+                # scatter has landed
+                for bk in (pbank, *obanks.values()):
+                    bk.flush()
+                fp = dict(params, client=pbank.tree)
+                fo = dict(opt_state)
+                for mk, bk in obanks.items():
+                    fo[mk] = dict(opt_state[mk], client=bk.tree)
+                per_old = per_client_numel(fp["client"])
+                fp = alg.resplit_lm_params(fp, plans[cut], plans[v])
+                fo = alg.resplit_opt_state(fo, plans[cut], plans[v])
+                per_new = per_client_numel(fp["client"])
+                pbank.replace(fp["client"])
+                params = dict(fp, client=None)
+                opt_state = fo
+                for mk, bk in obanks.items():
+                    bk.replace(fo[mk]["client"])
+                    opt_state[mk] = dict(fo[mk], client=None)
                 if peft is None:
                     mb = migration_bits(client_param_numel(plans[cut]),
                                         client_param_numel(plans[v]),
@@ -342,60 +338,48 @@ def train_lm(args) -> dict:
                  "labels": jnp.asarray(labels.reshape(shape)),
                  "seed": round_seed(args.seed, i)}
         with _maybe_profile(args, i), rec.span("step", cut=cut):
-            if sampler is None:
-                # a PEFT step returns only the trainable tree: keep the base
-                new, opt_state, m = steps_by_cut[cut](params, opt_state,
-                                                      batch)
-                params = dict(params, **new)
-            else:
-                # partial participation: gather the step-i cohort (params +
-                # optimizer moments), train with unbiased cohort weights,
-                # scatter back (sfl broadcasts its new global client model)
+            # gather the step-i cohort (params + optimizer moments), train
+            # with unbiased cohort weights, scatter back (sfl broadcasts its
+            # new global client model). Full participation is the identity
+            # cohort: idx=None gathers the whole bank and scatters it back
+            # wholesale, the step itself having aggregated every row
+            idx, broadcast, nxt = None, False, None
+            if sampler is not None:
                 idx, w = sampler.cohort(i)
-                nxt = None
-                if pbank is None:
-                    cp = alg.gather_cohort(params, idx)
-                    cop = alg.gather_cohort_opt(opt_state, idx)
-                else:
-                    cp = dict(params, client=pbank.gather(idx, t=i))
-                    cop = dict(opt_state)
-                    for mk, bk in obanks.items():
-                        cop[mk] = dict(opt_state[mk],
-                                       client=bk.gather(idx, t=i))
-                    # disjoint next cohort: stage its slice while this
-                    # step trains (else wait until the scatter enqueues).
-                    # Aggregating schemes (sfl) BROADCAST-scatter — every
-                    # row rewrites, so disjointness proves nothing and
-                    # any early stage would be invalidated anyway; always
-                    # prefetch after the scatter there.
-                    nxt, _ = sampler.peek(i + 1)
-                    if not spec.client_aggregate \
-                            and np.intersect1d(idx, nxt).size == 0:
-                        pbank.prefetch(i + 1, nxt)
-                        for bk in obanks.values():
-                            bk.prefetch(i + 1, nxt)
-                        nxt = None
-                cp, cop, m = steps_by_cut[cut](
-                    cp, cop, dict(batch, rho=jnp.asarray(w)))
-                if pbank is None:
-                    params = alg.scatter_cohort(
-                        params, cp, idx,
-                        broadcast_client=spec.client_aggregate)
-                    opt_state = alg.scatter_cohort_opt(opt_state, cop, idx)
-                else:
-                    pbank.scatter(idx, cp["client"],
-                                  broadcast=spec.client_aggregate)
-                    params = dict(params, server=cp["server"])
-                    opt_state = dict(cop)
-                    for mk, bk in obanks.items():
-                        # moments scatter per-row even under sfl: each
-                        # client keeps its OWN moment history
-                        bk.scatter(idx, cop[mk]["client"])
-                        opt_state[mk] = dict(cop[mk], client=None)
-                    if nxt is not None:
-                        pbank.prefetch(i + 1, nxt)
-                        for bk in obanks.values():
-                            bk.prefetch(i + 1, nxt)
+                batch = dict(batch, rho=jnp.asarray(w))
+                broadcast = spec.client_aggregate
+            cp = dict(params, client=pbank.gather(idx, t=i))
+            cop = dict(opt_state)
+            for mk, bk in obanks.items():
+                cop[mk] = dict(opt_state[mk], client=bk.gather(idx, t=i))
+            if pbank.prefetch_enabled:
+                # disjoint next cohort: stage its slice while this step
+                # trains (else wait until the scatter enqueues).
+                # Aggregating schemes (sfl) BROADCAST-scatter — every row
+                # rewrites, so disjointness proves nothing and any early
+                # stage would be invalidated anyway; always prefetch after
+                # the scatter there.
+                nxt, _ = sampler.peek(i + 1)
+                if not broadcast and np.intersect1d(idx, nxt).size == 0:
+                    pbank.prefetch(i + 1, nxt)
+                    for bk in obanks.values():
+                        bk.prefetch(i + 1, nxt)
+                    nxt = None
+            # a PEFT step returns only the trainable tree: params keeps
+            # the base
+            cp, cop, m = steps_by_cut[cut](cp, cop, batch)
+            pbank.scatter(idx, cp["client"], broadcast=broadcast)
+            params = dict(params, server=cp["server"])
+            opt_state = dict(cop)
+            for mk, bk in obanks.items():
+                # moments scatter per-row even under sfl: each client
+                # keeps its OWN moment history
+                bk.scatter(idx, cop[mk]["client"])
+                opt_state[mk] = dict(cop[mk], client=None)
+            if nxt is not None:
+                pbank.prefetch(i + 1, nxt)
+                for bk in obanks.values():
+                    bk.prefetch(i + 1, nxt)
             losses.append(float(m["loss"]))  # sync point inside the span
         if rec.enabled:
             jax.effects_barrier()  # drain the step's ledger callbacks
@@ -413,26 +397,26 @@ def train_lm(args) -> dict:
         if (i + 1) % args.log_every == 0:
             obs.log(f"step {i+1}/{done+args.steps} loss {losses[-1]:.4f} "
                     f"({(time.time()-t0)/(i+1-done):.2f} s/step)")
-    if pbank is not None:
-        # close() drains the pipeline AND releases the worker threads;
-        # the banks stay readable for the stats/checkpoint reads below
-        pbank.close()
-        for bk in obanks.values():
-            bk.close()
-        st = pbank.stats()
+    # close() drains the pipeline AND releases the worker threads; the
+    # banks stay readable for the stats/checkpoint reads below
+    pbank.close()
+    for bk in obanks.values():
+        bk.close()
+    st = pbank.stats()
+    if args.bank == "host":
         obs.log(f"bank[host]: peak device client-state "
                 f"{st['device_bytes_peak'] / 1e6:.2f} MB of "
                 f"{st['bank_bytes'] / 1e6:.2f} MB bank; prefetch "
                 f"{st['prefetch_hits']} hits / {st['prefetch_misses']} "
                 f"misses")
-        if rec.enabled:
-            rec.event("bank", name="bank", **st)
+    if rec.enabled:
+        rec.event("bank", name="bank", **st)
     if args.checkpoint:
         # payload carries params AND optimizer state with full-bank
         # client trees (residency-agnostic: the host banks' numpy rows
         # serialize identically to device arrays), so --resume is
         # bit-exact under any --bank backend
-        pl = params if pbank is None else dict(params, client=pbank.tree)
+        pl = dict(params, client=pbank.tree)
         ol = dict(opt_state)
         for mk, bk in obanks.items():
             ol[mk] = dict(opt_state[mk], client=bk.tree)
@@ -526,14 +510,15 @@ class _LMAsyncExecutor:
         import jax
         import jax.numpy as jnp
 
+        from repro.core.bank import scatter_rows
+
         params = self.state["params"]
         idx = jnp.asarray(
             np.asarray([it["client"] for it in items], np.int64))
         rows = [jax.tree.map(lambda x, p=it["pos"]: x[p],
                              it["payload"]["client"]) for it in items]
         stacked = jax.tree.map(lambda *xs: jnp.stack(xs), *rows)
-        client = jax.tree.map(lambda b, u: b.at[idx].set(u),
-                              params["client"], stacked)
+        client = scatter_rows(params["client"], stacked, idx)
         # generation-granular server merge: fold a generation's delta in
         # when its LAST member completes, at that merge's staleness
         done = []

@@ -602,29 +602,66 @@ class TestLMCohort:
             assert got["server"] is (params if move == "gather"
                                      else cohort)["server"]
 
-    @pytest.mark.parametrize("helper", ["_gather_rows", "_scatter_rows",
-                                        "_broadcast_row0"])
+    @pytest.mark.parametrize("helper", ["gather_rows", "scatter_rows",
+                                        "broadcast_row0"])
     def test_cohort_programs_compile_once_per_k(self, helper):
         """The cohort index is traced: cohorts of one size K share one
         program, and another K adds exactly one."""
         from repro.core import algorithms as alg
+        from repro.core import bank as bank_mod
 
         _, _, _, _, params = self._setup(n=5)
-        fn = getattr(alg, helper)
+        fn = getattr(bank_mod, helper)
         fn.clear_cache()
 
         def move(idx):
             cohort = alg.gather_cohort(params, idx)
-            if helper == "_gather_rows":
+            if helper == "gather_rows":
                 return
             alg.scatter_cohort(params, cohort, idx,
-                               broadcast_client=helper == "_broadcast_row0")
+                               broadcast_client=helper == "broadcast_row0")
 
         for idx in ([0, 1, 2], [4, 2, 3], [3, 0, 1]):
             move(np.asarray(idx))
         assert fn._cache_size() == 1
         move(np.asarray([1, 4]))
         assert fn._cache_size() == 2
+
+    @pytest.mark.parametrize("program", ["gather_rows", "scatter_rows",
+                                         "broadcast_row0"])
+    def test_bank_and_cohort_helpers_share_one_program(self, program):
+        """A ``device`` ClientBank and ``gather_cohort``/``scatter_cohort``
+        on the same client tree run one compiled program between them —
+        one launch for the whole L-leaf tree, not one per leaf — and
+        give the same rows."""
+        from repro.core import algorithms as alg
+        from repro.core import bank as bank_mod
+
+        _, _, _, _, params = self._setup(n=5)
+        assert len(jax.tree.leaves(params["client"])) > 1
+        fn = getattr(bank_mod, program)
+        fn.clear_cache()
+        bank = bank_mod.ClientBank(params["client"], n_clients=5,
+                                   stacked=True, backend="device")
+        idx = np.asarray([3, 0, 4])
+        broadcast = program == "broadcast_row0"
+        got = bank.gather(idx)
+        if program != "gather_rows":
+            upd = jax.tree.map(lambda x: x * 2.0 + 1.0, got)
+            bank.scatter(idx, upd, broadcast=broadcast)
+            got = bank.tree
+        assert fn._cache_size() == 1  # the bank compiled it
+        cohort = alg.gather_cohort(params, idx)
+        want = cohort["client"]
+        if program != "gather_rows":
+            bumped = dict(cohort, client=jax.tree.map(
+                lambda x: x * 2.0 + 1.0, want))
+            want = alg.scatter_cohort(params, bumped, idx,
+                                      broadcast_client=broadcast)["client"]
+        assert fn._cache_size() == 1  # ... and the helpers reused it
+        assert jax.tree.structure(got) == jax.tree.structure(want)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
     def test_partial_step_leaves_nonparticipants(self):
         from repro.core import algorithms as alg
