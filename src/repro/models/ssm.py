@@ -46,34 +46,41 @@ def ssd_chunked(x, dt, A, B, C, chunk: int,
     """Chunked SSD. x:(b,s,h,p) dt:(b,s,h) A:(h,) B,C:(b,s,g,n).
 
     Returns (y (b,s,h,p), final_state (b,h,p,n)). All math in fp32.
+
+    B and C stay in group form: the heads are viewed as (g, r = h/g), head
+    ``j`` reading group ``j // r``, so ``C B^T`` is formed once a group and
+    chunk and neither B nor C is repeated over heads. With ``g == h`` this
+    is the per-head computation. Each contraction is a pairwise einsum.
     """
     b, s, h, p = x.shape
     g, n = B.shape[2], B.shape[3]
     assert s % chunk == 0, (s, chunk)
     c = s // chunk
-    rep = h // g
+    r = h // g
     dtf = dt.astype(jnp.float32)
     xdt = x.astype(jnp.float32) * dtf[..., None]  # fold dt into x
     dA = dtf * A.astype(jnp.float32)  # (b,s,h) log-decay
-    Bf = jnp.repeat(B.astype(jnp.float32), rep, axis=2)  # (b,s,h,n)
-    Cf = jnp.repeat(C.astype(jnp.float32), rep, axis=2)
 
-    # -> chunked views
-    xc = xdt.reshape(b, c, chunk, h, p)
-    Bc = Bf.reshape(b, c, chunk, h, n)
-    Cc = Cf.reshape(b, c, chunk, h, n)
+    # -> chunked views, heads as (g, r)
+    xc = xdt.reshape(b, c, chunk, g, r, p)
+    Bc = B.astype(jnp.float32).reshape(b, c, chunk, g, n)
+    Cc = C.astype(jnp.float32).reshape(b, c, chunk, g, n)
     Ac = dA.reshape(b, c, chunk, h).transpose(0, 3, 1, 2)  # (b,h,c,q)
     A_cs = jnp.cumsum(Ac, axis=-1)  # (b,h,c,q)
 
-    # 1) intra-chunk (dual quadratic form)
-    L = jnp.exp(segsum(Ac))  # (b,h,c,q,q)
-    y_diag = jnp.einsum("bclhn,bcshn,bhcls,bcshp->bclhp", Cc, Bc, L, xc,
+    # 1) intra-chunk (dual quadratic form): C B^T once a group, then the
+    # per-head decays, then the values
+    CB = jnp.einsum("bclgn,bcsgn->bgcls", Cc, Bc, precision=F32)
+    L = jnp.exp(segsum(Ac)).reshape(b, g, r, c, chunk, chunk)
+    y_diag = jnp.einsum("bgrcls,bcsgrp->bclgrp", L * CB[:, :, None], xc,
                         precision=F32)
 
     # 2) chunk-final states
     decay_states = jnp.exp(A_cs[..., -1:] - A_cs)  # (b,h,c,q)
-    states = jnp.einsum("bclhn,bhcl,bclhp->bchpn", Bc, decay_states, xc,
-                        precision=F32)
+    x_decayed = xc * decay_states.reshape(b, g, r, c, chunk).transpose(
+        0, 3, 4, 1, 2)[..., None]
+    states = jnp.einsum("bclgn,bclgrp->bcgrpn", Bc, x_decayed,
+                        precision=F32).reshape(b, c, h, p, n)
 
     # 3) inter-chunk recurrence via the chunk-level decay matrix
     if initial_state is None:
@@ -85,9 +92,11 @@ def ssd_chunked(x, dt, A, B, C, chunk: int,
     prev_states, final_state = new_states[:, :-1], new_states[:, -1]
 
     # 4) inter-chunk contribution to outputs
-    state_decay_out = jnp.exp(A_cs)  # (b,h,c,q)
-    y_off = jnp.einsum("bclhn,bchpn,bhcl->bclhp", Cc, prev_states,
-                       state_decay_out, precision=F32)
+    state_decay_out = jnp.exp(A_cs).reshape(b, g, r, c, chunk).transpose(
+        0, 3, 4, 1, 2)  # (b,c,q,g,r)
+    y_off = jnp.einsum("bclgn,bcgrpn->bclgrp", Cc,
+                       prev_states.reshape(b, c, g, r, p, n),
+                       precision=F32) * state_decay_out[..., None]
 
     y = (y_diag + y_off).reshape(b, s, h, p)
     return y.astype(x.dtype), final_state
