@@ -141,8 +141,8 @@ class Setup:
             loras = lm.init_lm_loras(key, plan, jnp.dtype(dtype))
             return alg.split_lm_lora_params(base, loras, N)
 
-        self.params = weights.make(jax.eval_shape(init, jax.random.key(0)),
-                                   seed, alpha, rank, adapters=True,
+        shapes = jax.eval_shape(init, jax.random.key(0))
+        self.params = weights.make(shapes, seed, alpha, rank, adapters=True,
                                    ssm=cfg.ssm)
         self.base = self.params["base"]
         opt = make_optimizer(tj["optimizer"], lr)
@@ -152,9 +152,9 @@ class Setup:
         self.step_fn = jax.jit(alg.make_train_step(plan, tcfg, opt, K))
         self.codec_seed0 = int(generate.rng(seed, "order").integers(0, 2**32))
         self.shape, self.vocab = (K, b, S), cfg.vocab_size
-        self.flops = flops.train_step_flops(cfgj, kinds=layer_specs(cfg),
-                                            cohort=K, batch=b, seq=S,
-                                            rank=rank)
+        self.flops = flops.train_step_flops(
+            cfgj, jax.eval_shape(weights.plain, shapes["base"])["layers"],
+            kinds=layer_specs(cfg), cohort=K, batch=b, seq=S, rank=rank)
         # the first steps, through the window's own loop
         self.snaps = [self.trainable()]
         self.losses, self.fed, took = [], [], []

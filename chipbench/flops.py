@@ -1,4 +1,13 @@
-"""Operations and bytes that a step needs, from the configuration's sizes.
+"""Operations and bytes that a step needs, from the configuration's sizes
+and, for an expert layer, from the weights the program holds.
+
+An expert layer's widths are read from the layer's weight shapes in the
+plain per-layer layout of ``chipbench/weights.py`` (``moe_router``,
+``moe_w_gate``/``_up``/``_down``, ``moe_shared_gate``/``_up``/``_down``):
+the experts held, their width, the router's width and the shared
+expert's, so a layer that holds a share of its experts is counted by
+that share. Only ``top_k``, a property of the router, comes from the
+configuration.
 
 "Needs" is the algorithm's count, not what the program happens to compute:
 causal attention counts its lower triangle, a frozen weight's gradient is
@@ -33,9 +42,10 @@ def _ssm(cfg):
 def layer_modules(cfg, kind=DENSE) -> dict:
     """{module: {weight: (d_in, d_out)}} of the matmul weights that every
     token of one layer of ``kind`` runs through, keyed by the LoRA target
-    that covers them (``attn``, ``ssm``, ``mlp``, ``router``; ``shared``,
-    the MoE's shared experts, is never one). The routed experts are
-    counted apart, ``top_k`` a token."""
+    that covers them (``attn``, ``ssm``, ``mlp``), from the
+    configuration's sizes. An expert layer's router, shared expert and
+    routed experts are none of them: ``expert_flops`` counts them from
+    the layer's weights."""
     mixer, ffn = kind
     d = cfg["d_model"]
     out = {}
@@ -54,14 +64,39 @@ def layer_modules(cfg, kind=DENSE) -> dict:
         out["mlp"] = {"up": (d, f), "down": (f, d)}
         if cfg["mlp_act"] == "swiglu":
             out["mlp"]["gate"] = (d, f)
-    elif ffn == "moe":
-        m = cfg["moe"]
-        out["router"] = {"router": (d, m["num_experts"])}
-        if m.get("num_shared_experts"):
-            f = m["d_ff_expert"] * m["num_shared_experts"]
-            out["shared"] = {"shared_gate": (d, f), "shared_up": (d, f),
-                             "shared_down": (f, d)}
     return out
+
+
+def expert_flops(layer, top_k: int, tokens: int) -> int:
+    """Forward and input gradient (4 per weight per token) of one expert
+    layer's frozen matmuls over ``tokens``, read from the layer's weights
+    (shapes suffice) in the plain layout of ``weights.plain``:
+
+    * the router, ``(d, E_total)``, ``E_total`` the output axis of
+      ``moe_router``: every token;
+    * the shared expert, three matmuls of ``(d, F_sh)``, ``F_sh`` the
+      output axis of ``moe_shared_gate``: every token; none where the
+      layer has no ``moe_shared_*``;
+    * the routed experts, three SwiGLU matmuls of ``(d, f)`` for each of
+      the ``top_k`` assignments of a token that lands on an expert held
+      here: ``E_held``, the leading axis of ``moe_w_gate`` (and of
+      ``moe_w_up`` and ``moe_w_down``), of the router's ``E_total``.
+
+    The routed term is an expectation over routing: a token's
+    assignments land on the held experts in the share ``E_held /
+    E_total``, in integer arithmetic (floored). With the seeded random
+    router the share that a step realises varies from step to step, and
+    tokens that a full expert drops are counted as served."""
+    gate, up, down = (layer[k].shape
+                      for k in ("moe_w_gate", "moe_w_up", "moe_w_down"))
+    held, d, f = gate
+    if up != gate or down != (held, f, d):
+        raise ValueError(f"expert stacks disagree: {gate}, {up}, {down}")
+    routed = layer["moe_router"].shape[1]
+    shared = layer["moe_shared_gate"].shape[1] \
+        if "moe_shared_gate" in layer else 0
+    every = d * routed + 3 * d * shared
+    return 4 * tokens * every + 4 * tokens * top_k * 3 * d * f * held // routed
 
 
 def layer_mats(cfg, kind=DENSE) -> dict:
@@ -118,12 +153,14 @@ def ssd_flops(cfg, seq: int) -> int:
             + 4 * tail * heads * n * p)
 
 
-def train_step_flops(cfg, *, kinds, cohort: int, batch: int, seq: int,
-                     rank: int) -> int:
+def train_step_flops(cfg, layers, *, kinds, cohort: int, batch: int,
+                     seq: int, rank: int) -> int:
     """A split LoRA step over ``cohort * batch`` sequences of ``seq``,
     counted layer by layer by its kind: ``kinds`` holds the (mixer, ffn)
     of each layer as the program's ``ModelConfig`` decides it
-    (``transformer.layer_specs``).
+    (``transformer.layer_specs``), ``layers`` the weights of each layer in
+    the plain layout, ``jax.eval_shape(weights.plain, base)["layers"]`` of
+    the program's base tree (shapes suffice).
 
     Forward through every matmul and the head (2 per weight per token);
     backward needs activation gradients through every layer above the
@@ -132,13 +169,15 @@ def train_step_flops(cfg, *, kinds, cohort: int, batch: int, seq: int,
     ``in_proj``, and a parallel block's MLP): nothing below them trains.
     The adapters need their forward, input and weight
     gradients (6 per adapter weight per token). Causal attention and the
-    SSD count forward once and backward twice. A MoE layer's router and
-    shared experts count as matmuls; its routed experts as ``top_k``
-    SwiGLU experts a token (tokens a full expert drops are counted as
-    served)."""
+    SSD count forward once and backward twice. An expert layer counts as
+    ``expert_flops`` reads it from ``layers``: its router and shared
+    expert as matmuls, its routed experts as the expected share of the
+    ``top_k`` SwiGLU experts a token (``cfg["moe"]["top_k"]``) that lands
+    on the experts the layer holds; tokens a full expert drops are counted
+    as served."""
     T, rows = cohort * batch * seq, cohort * batch
     total = 4 * head_params(cfg) * T
-    for i, kind in enumerate(kinds):
+    for i, (kind, layer) in enumerate(zip(kinds, layers, strict=True)):
         mats = layer_mats(cfg, kind)
         total += 4 * T * sum(a * b for a, b in mats.values())
         total += 6 * T * layer_lora(cfg, kind, rank)
@@ -153,9 +192,7 @@ def train_step_flops(cfg, *, kinds, cohort: int, batch: int, seq: int,
         else:
             total += 3 * rows * ssd_flops(cfg, seq)
         if kind[1] == "moe":
-            m = cfg["moe"]
-            total += 4 * T * m["top_k"] * 3 * cfg["d_model"] \
-                * m["d_ff_expert"]
+            total += expert_flops(layer, cfg["moe"]["top_k"], T)
     return total
 
 

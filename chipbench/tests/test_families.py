@@ -207,9 +207,133 @@ def test_train_step_flops_within_xla_count(arch):
     cfg = STEPS[arch]
     K, b, S = 2, 1, 128
     xla = test_flops.xla_step_flops(cfg, K, b, S, RANK)
-    need = flops.train_step_flops(_json(cfg), kinds=tf.layer_specs(cfg),
-                                  cohort=K, batch=b, seq=S, rank=RANK)
+    need = flops.train_step_flops(_json(cfg), test_flops.plain_layers(cfg),
+                                  kinds=tf.layer_specs(cfg), cohort=K,
+                                  batch=b, seq=S, rank=RANK)
     assert BAND[FAMILY[cfg.arch_type]] * xla <= need <= xla
+
+
+# ------------------------------------------------ the experts a layer holds
+MOE = sorted(a for a, c in STEPS.items() if c.moe is not None)
+EXPERTS = ("moe_w_gate", "moe_w_up", "moe_w_down")
+T = 2 * 1 * 128  # tokens of the step counted below
+
+
+def _moe(arch):
+    """(configuration file, layer kinds, plain layer shapes) of a preset."""
+    cfg = STEPS[arch]
+    return _json(cfg), tf.layer_specs(cfg), test_flops.plain_layers(cfg)
+
+
+def _count(cfgj, kinds, layers):
+    return flops.train_step_flops(cfgj, layers, kinds=kinds, cohort=2,
+                                  batch=1, seq=128, rank=RANK)
+
+
+def _hold(layers, lo, hi):
+    """Each expert layer holding experts ``lo`` to ``hi - 1`` of its
+    stacks: their expert axis sliced, the router left whole."""
+    return jax.eval_shape(lambda ls: [
+        {k: v[lo:hi] if k in EXPERTS else v for k, v in layer.items()}
+        for layer in ls], layers)
+
+
+def _experts(layers, held, f, routed, shared):
+    """Each expert layer with ``held`` experts of width ``f``, a router
+    of width ``routed`` and a shared expert of width ``shared`` (0:
+    none)."""
+    def sd(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32)
+
+    out = []
+    for layer in layers:
+        if "moe_router" in layer:
+            d = layer["moe_router"].shape[0]
+            layer = {k: v for k, v in layer.items()
+                     if not k.startswith("moe_")}
+            layer.update(moe_router=sd(d, routed), moe_w_gate=sd(held, d, f),
+                         moe_w_up=sd(held, d, f), moe_w_down=sd(held, f, d))
+            if shared:
+                layer.update(moe_shared_gate=sd(d, shared),
+                             moe_shared_up=sd(d, shared),
+                             moe_shared_down=sd(shared, d))
+        out.append(layer)
+    return out
+
+
+def _moe_layers(kinds):
+    return sum(ffn == "moe" for _, ffn in kinds)
+
+
+@pytest.mark.parametrize("n", [2, 4])
+@pytest.mark.parametrize("arch", MOE)
+def test_held_share_scales_only_the_routed_experts(arch, n):
+    cfgj, kinds, layers = _moe(arch)
+    m = cfgj["moe"]
+    routed = _moe_layers(kinds) * 4 * T * m["top_k"] * 3 * cfgj["d_model"] \
+        * m["d_ff_expert"]
+    part = _hold(layers, 0, m["num_experts"] // n)
+    assert _count(cfgj, kinds, layers) - _count(cfgj, kinds, part) \
+        == routed - routed // n
+
+
+@pytest.mark.parametrize("n", [2, 4])
+@pytest.mark.parametrize("arch", MOE)
+def test_held_shares_sum_to_the_whole_layer(arch, n):
+    # what every chip computes alike (mixers, router, shared expert, head,
+    # adapters) once, and each share of the routed experts
+    cfgj, kinds, layers = _moe(arch)
+    E = cfgj["moe"]["num_experts"]
+    shares = [_count(cfgj, kinds, _hold(layers, i * E // n, (i + 1) * E // n))
+              for i in range(n)]
+    alike = _count(cfgj, kinds, _hold(layers, 0, 0))
+    assert sum(shares) - (n - 1) * alike == _count(cfgj, kinds, layers)
+
+
+@pytest.mark.parametrize("arch", MOE)
+def test_router_width_is_its_output_axis(arch):
+    # granite-4.0-h-small cut to one chip: 9 of 72 experts held, the router
+    # at its full width, while ``num_experts`` holds the held count
+    cfgj, kinds, layers = _moe(arch)
+    cfgj = dict(cfgj, moe=dict(cfgj["moe"], num_experts=9))
+    d, f, k = cfgj["d_model"], cfgj["moe"]["d_ff_expert"], cfgj["moe"]["top_k"]
+    n = _moe_layers(kinds)
+    held = _count(cfgj, kinds, _experts(layers, 9, f, 72, 0))
+    none = _count(cfgj, kinds, _experts(layers, 0, f, 72, 0))
+    narrow = _count(cfgj, kinds, _experts(layers, 0, f, 9, 0))
+    assert held - none == n * 4 * T * k * 3 * d * f * 9 // 72
+    assert none - narrow == n * 4 * T * d * (72 - 9)
+
+
+@pytest.mark.parametrize("arch", MOE)
+def test_shared_width_is_its_output_axis(arch):
+    # granite-4.0-h-small's shared expert: 1536 wide beside experts of 768
+    cfgj, kinds, layers = _moe(arch)
+    cfgj = dict(cfgj, moe=dict(cfgj["moe"], d_ff_expert=768,
+                               num_shared_experts=1))
+    E = cfgj["moe"]["num_experts"]
+    wide = _count(cfgj, kinds, _experts(layers, E, 768, E, 1536))
+    none = _count(cfgj, kinds, _experts(layers, E, 768, E, 0))
+    assert wide - none == _moe_layers(kinds) * 4 * T * 3 * cfgj["d_model"] \
+        * 1536
+
+
+@pytest.mark.parametrize("key,value", [
+    ("num_experts", 9), ("d_ff_expert", 768), ("num_shared_experts", 2)])
+@pytest.mark.parametrize("arch", MOE)
+def test_tree_decides_the_expert_widths(arch, key, value):
+    cfgj, kinds, layers = _moe(arch)
+    changed = dict(cfgj, moe=dict(cfgj["moe"], **{key: value}))
+    assert _count(changed, kinds, layers) == _count(cfgj, kinds, layers)
+
+
+def test_expert_stacks_must_agree():
+    cfgj, kinds, layers = _moe("tiny-hybrid-moe")
+    torn = [dict(layer, moe_w_gate=jax.eval_shape(lambda v: v[:1],
+                                                  layer["moe_w_gate"]))
+            for layer in layers]
+    with pytest.raises(ValueError):
+        _count(cfgj, kinds, torn)
 
 
 @pytest.mark.parametrize("groups,band", [(4, 0.55), (1, 0.45)])
@@ -270,11 +394,21 @@ def test_setup_trains_a_hybrid_moe():
 def test_dense_counts_are_unchanged():
     cfgj = json.loads((Path(__file__).parents[1] / "configs" /
                        "granite-8b-l4.json").read_text())
-    kinds = tf.layer_specs(train.model_config(cfgj))
-    assert flops.train_step_flops(cfgj, kinds=kinds, cohort=4, batch=1,
-                                  seq=512, rank=8) == 8828305276928
+    cfg = train.model_config(cfgj)
+    assert flops.train_step_flops(cfgj, test_flops.plain_layers(cfg),
+                                  kinds=tf.layer_specs(cfg), cohort=4,
+                                  batch=1, seq=512, rank=8) == 8828305276928
     assert flops.layer_params(cfgj) == 218103808
     assert flops.weight_bytes(cfgj) == 4295114752
+
+
+def test_hybrid_cell_count_is_unchanged():
+    cfgj = json.loads((Path(__file__).parents[1] / "configs" /
+                       "granite-4.0-h-micro-l10.json").read_text())
+    cfg = train.model_config(cfgj)
+    assert flops.train_step_flops(cfgj, test_flops.plain_layers(cfg),
+                                  kinds=tf.layer_specs(cfg), cohort=4,
+                                  batch=1, seq=512, rank=8) == 7960191303680
 
 
 @pytest.mark.parametrize("config,digest", [

@@ -9,7 +9,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from chipbench import flops
+from chipbench import flops, weights
 from chipbench.drivers.train import model_config
 from chipbench.tests import small
 from repro.models.transformer import layer_specs
@@ -20,6 +20,16 @@ CFG = dict(small.CONFIG, num_layers=2, d_ff=1024, vocab_size=2048)
 def _xla_flops(compiled):
     ca = compiled.cost_analysis()
     return (ca[0] if isinstance(ca, list) else ca)["flops"]
+
+
+def plain_layers(cfg):
+    """Each layer's weight shapes in the plain layout, as the driver hands
+    them to ``train_step_flops``."""
+    from repro.models import lm
+
+    base = jax.eval_shape(lambda k: lm.init_lm(k, lm.build_plan(cfg, 1)),
+                          jax.random.key(0))
+    return jax.eval_shape(weights.plain, base)["layers"]
 
 
 def xla_step_flops(cfg, K, b, S, r):
@@ -51,8 +61,9 @@ def test_train_step_flops_within_xla_count():
     K, b, S, r = 2, 1, 128, 4
     cfg = model_config(CFG)
     xla = xla_step_flops(cfg, K, b, S, r)
-    need = flops.train_step_flops(CFG, kinds=layer_specs(cfg), cohort=K,
-                                  batch=b, seq=S, rank=r)
+    need = flops.train_step_flops(CFG, plain_layers(cfg),
+                                  kinds=layer_specs(cfg), cohort=K, batch=b,
+                                  seq=S, rank=r)
     assert 0.85 * xla <= need <= xla
 
 
